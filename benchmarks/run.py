@@ -41,6 +41,8 @@ def _write_bench_div():
     """
     import jax
 
+    from repro.kernels import ops
+
     path = os.path.abspath(_BENCH_DIV)
     doc = {}
     if os.path.exists(path):
@@ -52,10 +54,11 @@ def _write_bench_div():
     doc["meta"] = {
         "jax": jax.__version__,
         "backend": jax.default_backend(),
-        "pallas_interpret": jax.default_backend() != "tpu",
-        "note": ("Pallas cells run interpret-mode off-TPU: their wall-clock "
-                 "is a functional proxy, not kernel perf. jnp-mode rows are "
-                 "compiled XLA. TPU re-run pending (ROADMAP open item)."),
+        "pallas_interpret": ops.INTERPRET,
+        "note": ("With pallas_interpret the Pallas cells ran in the "
+                 "interpreter: their wall-clock is a functional proxy, not "
+                 "kernel perf. Timings are for the backend named here and "
+                 "are not device numbers unless it is a TPU."),
     }
     for k in _BENCH_DIV_KEYS:
         if k in RESULTS:
@@ -559,55 +562,34 @@ def bench_serving():
 
 
 def bench_sharding():
-    """Mesh scaling: 1 vs 8 virtual devices, tiled divide + K-Means.
+    """Mesh scaling: a 1-device mesh against an all-device mesh, tiled
+    divide + K-Means, in this process (``repro.sharding.scaling``).
 
-    jax locks the device count at first init, so each point runs as a
-    subprocess (``repro.sharding.scaling``) under its own
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. At N=1 the
-    mesh-aware dispatch falls back to the single-device paths, so the pair
-    is a true sharded-vs-unsharded comparison — on this container all 8
-    virtual devices share one host CPU, so the speedup column measures
-    dispatch overhead and XLA's intra-host parallelism, not an 8x fleet
-    (recorded as-is in the ``sharding`` section of BENCH_div.json).
+    On the 1-device mesh the mesh-aware dispatch falls back to the
+    single-device paths, so the pair is a true sharded-vs-unsharded
+    comparison on the devices this process has. On the CPU backend, force
+    virtual devices before the run
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``); they share
+    one host CPU, so the speedup column then measures dispatch overhead and
+    XLA's intra-host parallelism, not a fleet.
     """
-    import subprocess
-    import sys
+    from repro.sharding import scaling
 
-    src = os.path.abspath(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), os.pardir, "src"))
     points = 200_000 if QUICK else 1_000_000
     rows_, cols = (1024, 256) if QUICK else (2048, 384)
-    reps = 2 if QUICK else 3
-    rows = {}
-    for n_dev in (1, 8):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        cmd = [sys.executable, "-m", "repro.sharding.scaling",
-               "--points", str(points), "--rows", str(rows_),
-               "--cols", str(cols), "--reps", str(reps)]
-        proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                              timeout=1800)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"scaling driver failed at {n_dev} device(s):\n"
-                f"{proc.stdout}\n{proc.stderr}")
-        data = json.loads(proc.stdout.strip().splitlines()[-1])
-        rows[f"devices{n_dev}"] = data
+    rows = scaling.measure_pair(points=points, rows=rows_, cols=cols,
+                                reps=2 if QUICK else 3)
+    for key, data in rows.items():
+        if key == "speedup":
+            continue
+        n_dev = data["devices"]
         print(f"sharding_divide_d{n_dev},{data['tiled_divide_us']:.1f},"
               f"shape={rows_}x{cols}")
         print(f"sharding_kmeans_d{n_dev},{data['kmeans_us']:.1f},"
               f"points={points};inertia={data['kmeans']['inertia']:.6f}")
-    rows["speedup_8dev"] = {
-        "tiled_divide": rows["devices1"]["tiled_divide_us"]
-        / rows["devices8"]["tiled_divide_us"],
-        "kmeans": rows["devices1"]["kmeans_us"]
-        / rows["devices8"]["kmeans_us"],
-    }
-    print(f"sharding_speedup,0,"
-          f"divide={rows['speedup_8dev']['tiled_divide']:.2f}x;"
-          f"kmeans={rows['speedup_8dev']['kmeans']:.2f}x")
+    sp = rows["speedup"]
+    print(f"sharding_speedup,0,devices={sp['devices']};"
+          f"divide={sp['tiled_divide']:.2f}x;kmeans={sp['kmeans']:.2f}x")
     RESULTS["sharding"] = rows
     _write_bench_div()
 
@@ -637,6 +619,9 @@ def main() -> None:
                     help="CI smoke sizing: one problem size per workload")
     args, _ = ap.parse_known_args()
     QUICK = args.quick
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

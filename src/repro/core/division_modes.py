@@ -49,7 +49,7 @@ from typing import Optional
 import numpy as np
 
 from . import goldschmidt, taylor
-from .fpparts import UNDERFLOW_POLICIES
+from .fpparts import UNDERFLOW_POLICIES, tree_sum
 from .seeds import compute_segments, rsqrt_seed_table
 
 __all__ = ["DivisionConfig", "recip", "div", "rsqrt", "softmax", "rmsnorm",
@@ -276,7 +276,9 @@ def softmax(x, axis: int = -1, cfg: DivisionConfig = TAYLOR, where=None):
     ex = jnp.exp(xf - jax.lax.stop_gradient(xmax))
     if where is not None:
         ex = jnp.where(where, ex, 0.0)
-    s = jnp.sum(ex, axis=axis, keepdims=True)
+    # One fixed summation order, shared with the fused kernel: the row-sum
+    # gate is about the unit's 1/s, so s must not move with the compiler.
+    s = jnp.moveaxis(tree_sum(jnp.moveaxis(ex, axis, -1)), -1, axis)
     # Fully-masked rows have ex == 0 lane-wise, so a divisor of 1 yields the
     # zero row exactly; rows with any surviving logit have s >= 1.
     safe = jnp.where(s == 0, jnp.ones_like(s), s)

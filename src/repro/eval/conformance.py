@@ -498,10 +498,10 @@ def _run_fanout(args, n: int) -> int:
     Workers re-derive the same deterministic cell list and take the
     interleaved slice ``cells[i::n]``, so the merged report
     (``merged[i::n] = shard_i``) restores the exact single-process cell
-    order. Each worker is its own jax process; on this container they share
-    the host CPU, on a multi-host fleet the same flag pins one shard per
-    process/device. A worker that dies without writing its report fails the
-    whole run.
+    order. Each worker is its own jax process, so this runs on the CPU
+    backend only: ``main`` refuses it on a TPU, where a chip belongs to one
+    process. A worker that dies without writing its report fails the whole
+    run.
     """
     import os
     import subprocess
@@ -557,6 +557,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.fanout and args.shard:
         ap.error("--fanout and --shard are mutually exclusive")
     if args.fanout and args.fanout > 1:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            # This process now holds the chip; a worker would fail or hang
+            # waiting for it. On a TPU the grid runs in one process.
+            ap.error("--fanout starts one JAX process per shard and cannot "
+                     "share a TPU; run the grid without --fanout")
         return _run_fanout(args, args.fanout)
 
     cells = default_grid(quick=args.quick)

@@ -18,7 +18,7 @@ from repro.core.seeds import SeedTable, compute_segments, rsqrt_seed_table
 # the fused kernels by tests/test_underflow_policy.py.
 from repro.core.fpparts import (  # noqa: F401  (re-exported kernel-side)
     F32_SIGN, F32_MAG_MASK, F32_EXP_MASK, F32_MAN_MASK, F32_ONE_BITS,
-    F32_IMPLICIT,
+    F32_IMPLICIT, tree_sum,
 )
 
 
@@ -73,7 +73,10 @@ def recip_f32_bits(x: jax.Array, table: SeedTable, n: int, schedule: str) -> jax
     man = jax.lax.bitcast_convert_type(man_bits | F32_ONE_BITS, jnp.float32)
     rman = series_refine(seed_ladder(man, table), man, n, schedule)  # (0.5, 1]
     # 2^-(exp-127) has biased exponent 254-exp; clamp into the normal range.
-    scale_exp = jnp.clip(jnp.uint32(254) - exp, jnp.uint32(0), jnp.uint32(254))
+    # The clamp runs in int32: Mosaic has no unsigned min/max. exp 0 and
+    # exp 255 lanes (the only ones the clamp touches) are overwritten by the
+    # edge selects below, so the unsigned and signed forms agree bit for bit.
+    scale_exp = jnp.clip(254 - exp.astype(jnp.int32), 0, 254).astype(jnp.uint32)
     scale = jax.lax.bitcast_convert_type(scale_exp << 23, jnp.float32)
     r = rman * scale
     # Edges: zero/denormal -> inf; inf -> 0; nan -> nan.

@@ -30,8 +30,8 @@ def _ilm_mul_kernel(a_ref, b_ref, o_ref, *, iters: int):
     one = jnp.uint32(1)
     for _ in range(iters):
         valid = (a > 0) & (b > 0)
-        k1 = _floor_log2(jnp.maximum(a, one))
-        k2 = _floor_log2(jnp.maximum(b, one))
+        k1 = _floor_log2(jnp.where(valid, a, one))
+        k2 = _floor_log2(jnp.where(valid, b, one))
         ra = a - (one << k1)
         rb = b - (one << k2)
         p = (one << (k1 + k2)) + (ra << k2) + (rb << k1)
@@ -47,7 +47,7 @@ def _ilm_square_kernel(a_ref, o_ref, *, iters: int):
     one = jnp.uint32(1)
     for _ in range(iters):
         valid = a > 0
-        k = _floor_log2(jnp.maximum(a, one))
+        k = _floor_log2(jnp.where(valid, a, one))
         r = a - (one << k)
         acc = jnp.where(valid, acc + (one << (k + k)) + (r << (k + one)), acc)
         a = jnp.where(valid, r, a)

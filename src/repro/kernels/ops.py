@@ -16,9 +16,11 @@ are masked against local extents inside the kernel — no all-gather, no
 resharding. Code already inside a shard_map body disables this with
 ``rules.suspend_mesh()``.
 
-On CPU (this container) kernels run with interpret=True; on TPU set
-``repro.kernels.ops.INTERPRET = False`` (the launcher does this when
-jax.default_backend() == 'tpu').
+Interpret mode: ``INTERPRET`` is true only when the default backend is the
+CPU, where the kernel bodies run in the Pallas interpreter (tests and
+rehearsals). On every other backend the kernels are compiled by Mosaic;
+nothing falls back to the interpreter there. A compile for a described,
+unattached TPU from a CPU process sets ``INTERPRET = False`` itself.
 """
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ from . import rmsnorm as rmsnorm_k
 from . import softmax as softmax_k
 from . import tsdiv as tsdiv_k
 
-INTERPRET = jax.default_backend() != "tpu"
+INTERPRET = jax.default_backend() == "cpu"
 
 # One definition of the f32 tile lattice, shared with the tiled kernels.
 _LANE = tsdiv_k.LANE
@@ -94,16 +96,15 @@ def _shard_rows(fn, mesh, axes, n_args: int):
     kernel on it directly — grid and block specs are recomputed from the
     local shape, so sharded operands stay resident end to end (zero
     collectives; the conformance for this is pinned in
-    tests/test_sharded_kernels.py). check_rep=False: the elementwise body
-    has no replication for shard_map's checker to track through the
+    tests/test_sharded_kernels.py). check_vma=False: the elementwise body
+    has no varying-axis types for shard_map's checker to track through the
     pallas_call.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(axes, None)
-    return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_args,
-                     out_specs=spec, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * n_args,
+                         out_specs=spec, check_vma=False)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))

@@ -59,7 +59,7 @@ def softmax_ref(x, *, n_iters: int = 2, precision_bits: int = 24,
     xf = x.astype(jnp.float32)
     xmax = jnp.max(xf, axis=-1, keepdims=True)
     ex = jnp.exp(xf - xmax)
-    s = jnp.sum(ex, axis=-1, keepdims=True)
+    s = common.tree_sum(ex)
     table = compute_segments(n_iters, precision_bits)
     return (ex * common.recip_f32_bits(s, table, n_iters, schedule)).astype(x.dtype)
 

@@ -26,7 +26,7 @@ def _softmax_kernel(x_ref, o_ref, *, n: int, precision_bits: int, schedule: str)
     # exact tag for them after the guard below.
     mfin = jnp.where(jnp.isfinite(xmax), xmax, jnp.float32(0.0))
     ex = jnp.exp(x - mfin)
-    s = jnp.sum(ex, axis=-1, keepdims=True)
+    s = common.tree_sum(ex)     # the jnp twin's summation order
     table = compute_segments(n, precision_bits)
     rs = common.recip_f32_bits(s, table, n, schedule)
     o_ref[...] = jnp.where(s == 0.0, jnp.float32(0.0),

@@ -202,8 +202,6 @@ def _probe_measure(cfg, shape, mesh, global_batch, n_dev, pod_size):
                              global_batch=global_batch)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     colls = rl.parse_collectives(compiled.as_text(), n_dev, pod_size)
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -8,15 +8,14 @@ never touches jax device state.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types landed after jax 0.4.x; Auto is the default either way."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int):
+    """GSPMD-propagated (Auto) axes: the sharding rules annotate, XLA plans."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -26,17 +25,19 @@ def make_production_mesh(*, multi_pod: bool = False,
     data = 256 // model
     shape = (2, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / examples).
+def make_host_mesh(model: int = 1,
+                   n_devices: Optional[int] = None) -> jax.sharding.Mesh:
+    """Small mesh over the first ``n_devices`` devices (default: all).
 
     Raises ValueError (not a bare assert, which ``python -O`` strips into a
-    garbage-shaped mesh) when ``model`` exceeds or doesn't divide the host's
+    garbage-shaped mesh) when ``model`` exceeds or doesn't divide the
     device count.
     """
-    n = jax.device_count()
+    devices = jax.devices()[:n_devices]
+    n = len(devices)
     if model < 1:
         raise ValueError(f"model={model} must be >= 1")
     if model > n:
@@ -48,4 +49,4 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
         raise ValueError(
             f"device count {n} is not divisible by model={model}")
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=_auto(2), devices=devices)

@@ -36,12 +36,13 @@ def main():
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
 
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.serving import ServingEngine
 
+    enable_compile_cache()
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     division = None
     if args.division_mode or args.n_iters or args.schedule:

@@ -35,8 +35,10 @@ def main():
     from repro.configs import get_config, get_smoke_config
     from repro.core.division_modes import DivisionConfig
     from repro.data import DataConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train.loop import LoopConfig, run
 
+    enable_compile_cache()
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     if args.division:
         cfg = dataclasses.replace(cfg, division=DivisionConfig(mode=args.division))

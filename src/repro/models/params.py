@@ -10,6 +10,7 @@ nothing). Logical axes map to mesh axes through the per-arch rules
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -180,7 +181,10 @@ def init_params(cfg: ModelConfig, key: jax.Array):
         if p.init == "ones":
             return jnp.ones(p.shape, dt)
         path_str = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, np.uint32(abs(hash(path_str)) % (2**31)))
+        # crc32, not hash(): str hashes are salted per process, and the
+        # weights must be the same in every process that passes this key.
+        k = jax.random.fold_in(key, np.uint32(zlib.crc32(path_str.encode())
+                                              % (2**31)))
         scale = p.scale if p.scale is not None else 1.0 / np.sqrt(p.shape[0])
         return (jax.random.normal(k, p.shape, jnp.float32) * scale).astype(dt)
 

@@ -153,15 +153,24 @@ class ServingEngine:
         self.params = params
         self.max_len = max_len
         self.eos_id = eos_id
-        self._decode = jax.jit(
-            lambda c, t, p: decode_step(cfg, params, c, t, p))
-        self._prefill_tok = jax.jit(
-            lambda t, l: prefill(cfg, params, t, lengths=l))
-        self._prefill_emb = jax.jit(
-            lambda e, l: prefill(cfg, params, None, embeds=e, lengths=l))
-        self._prefill_enc = jax.jit(
-            lambda t, enc, l: prefill(cfg, params, t, enc_embeds=enc,
-                                      lengths=l))
+        # The weights are an argument of every program, never a closure:
+        # a closed-over array is lowered as an HLO constant, which would
+        # bake the whole checkpoint into each prefill and decode program.
+        self._decode_fn = jax.jit(
+            lambda w, c, t, p: decode_step(cfg, w, c, t, p))
+        self._prefill_tok_fn = jax.jit(
+            lambda w, t, l: prefill(cfg, w, t, lengths=l))
+        self._prefill_emb_fn = jax.jit(
+            lambda w, e, l: prefill(cfg, w, None, embeds=e, lengths=l))
+        self._prefill_enc_fn = jax.jit(
+            lambda w, t, enc, l: prefill(cfg, w, t, enc_embeds=enc,
+                                         lengths=l))
+
+    def _decode(self, cache, tokens, pos):
+        return self._decode_fn(self.params, cache, tokens, pos)
+
+    def _prefill_tok(self, tokens, lengths):
+        return self._prefill_tok_fn(self.params, tokens, lengths)
 
     # ------------------------------------------------------------- alignment
 
@@ -225,15 +234,13 @@ class ServingEngine:
             emb = np.zeros((B, pad_to, cfg.d_model), np.float32)
             for i, e in enumerate(embeds):
                 emb[i, :lens[i]] = np.asarray(e, np.float32)
-            last_logits, cache = self._prefill_emb(jnp.asarray(emb), lengths)
+            last_logits, cache = self._prefill_emb_fn(
+                self.params, jnp.asarray(emb), lengths)
         else:
-            toks = np.zeros((B, pad_to), np.int32)
-            for i, p in enumerate(prompts):
-                toks[i, :len(p)] = p  # zero right-pad; pads are masked out
-            toks = jnp.asarray(toks)
+            toks = self._pad_prompts(prompts, pad_to)
             if cfg.is_encoder_decoder:
-                last_logits, cache = self._prefill_enc(
-                    toks, jnp.asarray(enc_embeds), lengths)
+                last_logits, cache = self._prefill_enc_fn(
+                    self.params, toks, jnp.asarray(enc_embeds), lengths)
             else:
                 last_logits, cache = self._prefill_tok(toks, lengths)
         cache = pad_cache_to(cache, pad_to, self.max_len, cfg)
@@ -255,6 +262,21 @@ class ServingEngine:
             tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             pos_v = pos_v + 1
         return outs
+
+    @staticmethod
+    def _pad_prompts(prompts, pad_to: int):
+        toks = np.zeros((len(prompts), pad_to), np.int32)
+        for i, p in enumerate(prompts):
+            toks[i, :len(p)] = p  # zero right-pad; pads are masked out
+        return jnp.asarray(toks)
+
+    def prefill_logits(self, prompts):
+        """(B, vocab) logits at each token prompt's last real position: the
+        prefill ``generate_batch`` runs, same padding and program, without
+        the decode."""
+        lens = [len(p) for p in prompts]
+        toks = self._pad_prompts(prompts, self._pad_to(max(lens)))
+        return self._prefill_tok(toks, jnp.asarray(lens, jnp.int32))[0]
 
     def generate(self, prompt_tokens=None, max_new: int = 32, *,
                  enc_embeds=None, embeds=None):

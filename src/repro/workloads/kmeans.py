@@ -55,6 +55,13 @@ class KMeansResult:
     inertia_trace: "object"
 
 
+# f32 products at f32 precision on every backend: a TPU's default f32 dot
+# rounds its operands to bf16, which would move distances and centroid sums
+# by ~1e-3 relative, far more than the division unit under test. The CPU
+# backend computes f32 dots in f32 either way.
+_F32_DOT = "highest"
+
+
 def pairwise_mean_sqdist(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
     """Mean squared distance plane ||x_n - c_k||^2 / D, shape (..., N, K).
 
@@ -67,7 +74,7 @@ def pairwise_mean_sqdist(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
 
     x2 = jnp.sum(x * x, axis=-1)[..., :, None]
     c2 = jnp.sum(c * c, axis=-1)[..., None, :]
-    xc = jnp.einsum("...nd,...kd->...nk", x, c)
+    xc = jnp.einsum("...nd,...kd->...nk", x, c, precision=_F32_DOT)
     d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
     return dm.div(d2, jnp.asarray(x.shape[-1], x.dtype), cfg)
 
@@ -96,7 +103,7 @@ def _block_cluster_sums(onehot, x, n_blocks: int):
     """(n_blocks, K, D) per-cluster sums over row-major row blocks."""
     import jax.numpy as jnp
 
-    parts = [jnp.einsum("nk,nd->kd", o, b)
+    parts = [jnp.einsum("nk,nd->kd", o, b, precision=_F32_DOT)
              for o, b in zip(jnp.split(onehot, n_blocks, axis=0),
                              jnp.split(x, n_blocks, axis=0))]
     return jnp.stack(parts)
@@ -116,7 +123,7 @@ def _cluster_sums(onehot, x):
 
     if x.ndim == 2 and x.shape[0] % _SUM_BLOCKS == 0:
         return _ordered_block_sum(_block_cluster_sums(onehot, x, _SUM_BLOCKS))
-    return jnp.einsum("...nk,...nd->...kd", onehot, x)
+    return jnp.einsum("...nk,...nd->...kd", onehot, x, precision=_F32_DOT)
 
 
 def lloyd_step(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
@@ -243,7 +250,6 @@ def kmeans_sharded(x, k: Optional[int] = None, *,
     blocked = (x.shape[0] % _SUM_BLOCKS == 0
                and _SUM_BLOCKS % n_shards == 0)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(xl, c0):
@@ -269,7 +275,8 @@ def kmeans_sharded(x, k: Optional[int] = None, *,
                     sums = _ordered_block_sum(parts)
                 else:
                     sums = jax.lax.psum(
-                        jnp.einsum("nk,nd->kd", onehot, xl), axes)
+                        jnp.einsum("nk,nd->kd", onehot, xl,
+                                   precision=_F32_DOT), axes)
                 inertia = dm.div(
                     jax.lax.psum(jnp.sum(jnp.min(d2, axis=-1)), axes),
                     n_total, cfg)
@@ -287,10 +294,10 @@ def kmeans_sharded(x, k: Optional[int] = None, *,
         return centroids, assign, inertia, trace
 
     pts = P(axes, None)
-    run = shard_map(
+    run = jax.shard_map(
         body, mesh=mesh, in_specs=(pts, P()),
         # Everything but the assignments is psum-replicated across the mesh.
-        out_specs=(P(), P(axes), P(), P()), check_rep=False)
+        out_specs=(P(), P(axes), P(), P()), check_vma=False)
     centroids, assign, inertia, trace = run(x, init)
     return KMeansResult(centroids=centroids, assignments=assign,
                         inertia=inertia, inertia_trace=trace)

@@ -175,7 +175,7 @@ def qr_givens_sharded(a, cfg: dm.DivisionConfig = dm.TAYLOR, *,
     if n_shards <= 1:
         return qr_givens_batched(a, cfg, via=via)
 
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
 
     def body(al):
@@ -183,5 +183,5 @@ def qr_givens_sharded(a, cfg: dm.DivisionConfig = dm.TAYLOR, *,
             return qr_givens_batched(al, cfg, via=via)
 
     spec = P(axes, None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec,),
-                     out_specs=(spec, spec), check_rep=False)(a)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                         out_specs=(spec, spec), check_vma=False)(a)

@@ -23,12 +23,14 @@ import jax.numpy as jnp
 from repro.core import division_modes as dm
 from repro.eval import conformance, golden
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _run_cli(args, timeout=600):
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", *args],
         capture_output=True, text=True, timeout=timeout,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src"}, cwd=ROOT)
 
 
 # ------------------------------------------------------------- exit codes
@@ -74,6 +76,53 @@ def test_conformance_main_exit_codes(monkeypatch, capsys):
     assert conformance.main(["--quick"]) == 1
     out = capsys.readouterr().out
     assert "CONFORMANCE FAILURES" in out
+
+
+@pytest.mark.parametrize("backend,refused", [("tpu", True), ("cpu", False)])
+def test_conformance_fanout_only_off_tpu(monkeypatch, capsys, backend,
+                                         refused):
+    """--fanout starts one JAX process per shard: refused on a TPU, whose
+    chip belongs to one process; dispatched on the CPU backend."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(conformance, "_run_fanout",
+                        lambda args, n: calls.append(n) or 0)
+    if refused:
+        with pytest.raises(SystemExit) as e:
+            conformance.main(["--quick", "--fanout", "2"])
+        assert e.value.code == 2 and calls == []
+        assert "cannot share a TPU" in capsys.readouterr().err
+    else:
+        assert conformance.main(["--quick", "--fanout", "2"]) == 0
+        assert calls == [2]
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache is the fixed .jax_cache/ at the root of the checkout."""
+    import pathlib
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = compile_cache.enable_compile_cache()
+    if env_dir is None:
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert got == str(root / ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", got)]
+    else:
+        assert got == env_dir and updates == []
 
 
 def test_golden_main_nonzero_on_failure(monkeypatch, capsys):

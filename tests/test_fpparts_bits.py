@@ -170,3 +170,21 @@ def test_div_pow2_scaling_invariance_pallas(mode):
     want = np.ldexp(q.astype(np.float64), k).astype(np.float32)
     np.testing.assert_array_equal(qk.view(np.uint32), want.view(np.uint32),
                                   err_msg=mode)
+
+
+@pytest.mark.parametrize("d", [128, 100, 1])
+def test_tree_sum_is_the_fixed_halving_order(d):
+    """tree_sum rounds the same f32 sums in the same order as an explicit
+    numpy halving tree over the zero-padded power-of-two width."""
+    from repro.core.fpparts import tree_sum
+
+    rng = np.random.default_rng(d)
+    x = np.exp(rng.normal(0.0, 4.0, (16, d))).astype(np.float32)
+    width = 1 << max(d - 1, 0).bit_length()
+    ref = np.concatenate([x, np.zeros((16, width - d), np.float32)], axis=1)
+    while ref.shape[1] > 1:
+        h = ref.shape[1] // 2
+        ref = (ref[:, :h] + ref[:, h:]).astype(np.float32)
+    got = np.asarray(tree_sum(jnp.asarray(x)))
+    assert got.shape == (16, 1)
+    np.testing.assert_array_equal(got.view(np.uint32), ref.view(np.uint32))

@@ -10,6 +10,7 @@ EOS release) reproduces ``generate()`` exactly; (d) embed-input and
 encoder-decoder configs get a working hand-off or a clear ``ValueError``.
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -31,6 +32,26 @@ def _setup(arch, *, max_len=96, **engine_kw):
         cfg = dataclasses.replace(cfg, encoder_seq=24)
     params = init_params(cfg, jax.random.PRNGKey(0))
     return cfg, params, ServingEngine(cfg, params, max_len=max_len, **engine_kw)
+
+
+# ------------------------------------------------ weights are arguments
+
+def test_engine_programs_take_weights_as_arguments():
+    """A jit closed over the weights lowers them as HLO constants, which
+    bakes the whole checkpoint into every prefill and decode program. The
+    engine's programs must take them as arguments instead."""
+    cfg, params, eng = _setup("paper_fpdiv")
+    toks = jnp.zeros((2, 8), jnp.int32)
+    lens = jnp.asarray([8, 5], jnp.int32)
+    text = eng._prefill_tok_fn.lower(params, toks, lens).as_text()
+    v, d = params["embed"].shape
+    assert f"tensor<{v}x{d}xf32>" in text          # the embedding is an input
+    assert not re.search(rf"stablehlo.constant dense<.*> : tensor<{v}x{d}x",
+                         text)
+    np.testing.assert_array_equal(
+        np.asarray(eng.prefill_logits([[1] * 8, [2] * 5])),
+        np.asarray(eng._prefill_tok(toks.at[0].set(1).at[1, :5].set(2),
+                                    lens)[0]))
 
 
 # --------------------------------------------------- padded-prompt identity

@@ -21,18 +21,20 @@ geometries on both sides. Tiny grid-(1,1) mostly-masked launches can drift
 shapes, same class as tests/test_jit_drift.py) — which is why the shapes
 here are production-sized and ragged, not minimal.
 """
+import os
 import subprocess
 import sys
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _ENV8 = 'os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"'
 
 
 def _run(snippet: str, sentinel: str):
     r = subprocess.run([sys.executable, "-c", snippet],
                        capture_output=True, text=True, timeout=600,
-                       env={**__import__("os").environ, "PYTHONPATH": "src",
+                       env={**os.environ, "PYTHONPATH": "src",
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo")
+                       cwd=ROOT)
     assert sentinel in r.stdout, r.stdout + r.stderr
 
 

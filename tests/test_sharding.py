@@ -1,4 +1,5 @@
 """Sharding rules: divisibility/duplicate drops + an 8-device SPMD subprocess."""
+import os
 import subprocess
 import sys
 
@@ -8,14 +9,14 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, rules_for
+from repro.launch.mesh import _auto
 from repro.sharding import rules as shr
 
-
-from repro.launch.mesh import _axis_type_kwargs as _axis_kwargs
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
 
 
 class TestSpecFor:
@@ -213,8 +214,8 @@ from repro.optim import adamw
 from repro.train import step as ts
 
 cfg = dataclasses.replace(get_smoke_config("llama3_8b"))
-from repro.launch.mesh import _axis_type_kwargs as _axis_kwargs
-mesh = jax.make_mesh((4, 2), ("data", "model"), **_axis_kwargs(2))
+from repro.launch.mesh import _auto
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=_auto(2))
 params = init_params(cfg, jax.random.PRNGKey(0))
 pshard = shr.param_shardings(cfg, mesh)
 params = jax.device_put(params, pshard)
@@ -235,13 +236,12 @@ import tempfile, numpy as np
 from repro.train import checkpoint as ck
 with tempfile.TemporaryDirectory() as d:
     ck.save(d, 1, new_state)
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"), **_axis_kwargs(2))
+    mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=_auto(2))
     pshard2 = shr.param_shardings(cfg, mesh2)
     state_shard2 = ts.TrainState(
         params=pshard2,
         opt=type(new_state.opt)(
-            step=jax.NamedSharding(mesh2, P()) if hasattr(jax, "NamedSharding")
-            else jax.sharding.NamedSharding(mesh2, P()),
+            step=jax.sharding.NamedSharding(mesh2, P()),
             m=pshard2, v=pshard2),
         step=jax.sharding.NamedSharding(mesh2, P()))
     _, restored = ck.restore_latest(d, new_state, shardings=state_shard2)
@@ -261,7 +261,7 @@ def test_real_8device_spmd_training():
                        capture_output=True, text=True, timeout=600,
                        env={**__import__("os").environ,
                             "PYTHONPATH": "src"},
-                       cwd="/root/repo")
+                       cwd=ROOT)
     assert "SPMD8 OK" in r.stdout, r.stdout + r.stderr
 
 
@@ -270,11 +270,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim import compress
 
-from repro.launch.mesh import _axis_type_kwargs as _axis_kwargs
-mesh = jax.make_mesh((2, 4), ("pod", "data"), **_axis_kwargs(2))
+from repro.launch.mesh import _auto
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=_auto(2))
 g = jnp.asarray(np.random.default_rng(0).normal(size=(2, 4, 64)), jnp.float32)
 err = jnp.zeros_like(g)
 
@@ -282,7 +281,7 @@ def body(g_blk, e_blk):
     mean, new_err = compress.psum_compressed(g_blk, e_blk, "pod")
     return mean, new_err
 
-f = shard_map(body, mesh=mesh, in_specs=(P("pod", "data"), P("pod", "data")),
+f = jax.shard_map(body, mesh=mesh, in_specs=(P("pod", "data"), P("pod", "data")),
               out_specs=(P("pod", "data"), P("pod", "data")))
 mean, new_err = jax.jit(f)(g, err)
 # cross-pod mean: both pods see the same mean; check vs exact
@@ -301,5 +300,5 @@ def test_int8_compressed_psum_on_pod_axis():
     r = subprocess.run([sys.executable, "-c", COMPRESS_SNIPPET],
                        capture_output=True, text=True, timeout=600,
                        env={**__import__("os").environ, "PYTHONPATH": "src"},
-                       cwd="/root/repo")
+                       cwd=ROOT)
     assert "COMPRESS8 OK" in r.stdout, r.stdout + r.stderr

@@ -13,6 +13,8 @@ from repro.configs import get_smoke_config
 from repro.models import init_params
 from repro.serving import ServingEngine
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def test_serving_engine_generates():
     cfg = get_smoke_config("paper_fpdiv")
@@ -46,7 +48,7 @@ def test_serving_greedy_deterministic():
 def _run(cmd, timeout=900):
     return subprocess.run(
         cmd, capture_output=True, text=True, timeout=timeout,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src"}, cwd=ROOT)
 
 
 def test_quickstart_example():
