@@ -44,6 +44,7 @@ shard_map body use ``rules.suspend_mesh()`` around their division sites.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import numpy as np
@@ -100,6 +101,26 @@ class DivisionConfig:
 EXACT = DivisionConfig(mode="exact")
 TAYLOR = DivisionConfig(mode="taylor")
 
+# Each public entry runs under the named scope ``dm.<entry>`` (``dm.div``,
+# ``dm.softmax``, ...), so that every operation it stages, the kernel launch
+# and the operand broadcasts and casts around it alike, carries that name in
+# the compiled program's op metadata and in a device trace. A scope is
+# compile-time metadata only: it adds no operation.
+SCOPE_PREFIX = "dm."
+
+
+def _scoped(fn):
+    scope = SCOPE_PREFIX + fn.__name__
+
+    @functools.wraps(fn)
+    def entry(*args, **kwargs):
+        import jax
+
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return entry
+
 
 def effective_underflow(cfg: DivisionConfig) -> str:
     """The subnormal policy a config actually delivers.
@@ -113,6 +134,7 @@ def effective_underflow(cfg: DivisionConfig) -> str:
     return cfg.underflow if cfg.mode in ("taylor", "goldschmidt") else "ftz"
 
 
+@_scoped
 def recip(x, cfg: DivisionConfig = TAYLOR):
 
     if cfg.mode == "exact":
@@ -142,6 +164,7 @@ def recip(x, cfg: DivisionConfig = TAYLOR):
     raise ValueError(cfg.mode)
 
 
+@_scoped
 def div(a, b, cfg: DivisionConfig = TAYLOR):
     """a/b through the exponent-separated datapath (never a * recip(b)).
 
@@ -191,6 +214,7 @@ def div(a, b, cfg: DivisionConfig = TAYLOR):
                          underflow=effective_underflow(cfg))
 
 
+@_scoped
 def rsqrt(x, cfg: DivisionConfig = TAYLOR):
     """1/sqrt(x) through the mode the config names — no silent fallthrough.
 
@@ -222,6 +246,7 @@ def rsqrt(x, cfg: DivisionConfig = TAYLOR):
                         underflow=effective_underflow(cfg))
 
 
+@_scoped
 def softmax(x, axis: int = -1, cfg: DivisionConfig = TAYLOR, where=None):
     """Numerically-stable softmax whose 1/sum goes through the division unit.
 
@@ -286,6 +311,7 @@ def softmax(x, axis: int = -1, cfg: DivisionConfig = TAYLOR, where=None):
     return out.astype(x.dtype)
 
 
+@_scoped
 def rmsnorm(x, w, cfg: DivisionConfig = TAYLOR, *, eps: float = 1e-6):
     """RMSNorm over the last dim; the 1/sqrt runs the configured mode.
 
@@ -323,6 +349,7 @@ def rmsnorm(x, w, cfg: DivisionConfig = TAYLOR, *, eps: float = 1e-6):
     return (xf * r * w.astype(jnp.float32)).astype(x.dtype)
 
 
+@_scoped
 def attention(q, k, v, cfg: DivisionConfig = TAYLOR, *, causal: bool = True):
     """Scaled dot-product attention with the softmax 1/l through the unit.
 

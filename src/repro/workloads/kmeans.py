@@ -61,6 +61,15 @@ class KMeansResult:
 # backend computes f32 dots in f32 either way.
 _F32_DOT = "highest"
 
+# Named scopes of the Lloyd phases, in ``kmeans`` and the ``kmeans_sharded``
+# body alike: compile-time op metadata (no operation) that names each phase's
+# work in the compiled program and in a device trace. The division unit's own
+# ``dm.*`` scopes nest inside them.
+SCOPE_DISTANCE = "kmeans.distance"   # the (N, K) plane and its 1/D
+SCOPE_ASSIGN = "kmeans.assign"       # argmin, min and the inertia
+SCOPE_UPDATE = "kmeans.update"       # one-hot, counts, sums, centroid divide
+SCOPE_FINAL = "kmeans.final"         # the assignment after the last update
+
 
 def pairwise_mean_sqdist(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
     """Mean squared distance plane ||x_n - c_k||^2 / D, shape (..., N, K).
@@ -70,23 +79,36 @@ def pairwise_mean_sqdist(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
     ``division_modes.div`` — for the Pallas modes the whole (N, K) plane
     streams through the tiled fused divide kernel.
     """
+    import jax
     import jax.numpy as jnp
 
-    x2 = jnp.sum(x * x, axis=-1)[..., :, None]
-    c2 = jnp.sum(c * c, axis=-1)[..., None, :]
-    xc = jnp.einsum("...nd,...kd->...nk", x, c, precision=_F32_DOT)
-    d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
-    return dm.div(d2, jnp.asarray(x.shape[-1], x.dtype), cfg)
+    with jax.named_scope(SCOPE_DISTANCE):
+        x2 = jnp.sum(x * x, axis=-1)[..., :, None]
+        c2 = jnp.sum(c * c, axis=-1)[..., None, :]
+        xc = jnp.einsum("...nd,...kd->...nk", x, c, precision=_F32_DOT)
+        d2 = jnp.maximum(x2 - 2.0 * xc + c2, 0.0)
+        return dm.div(d2, jnp.asarray(x.shape[-1], x.dtype), cfg)
 
 
-def _assign_and_inertia(x, c, cfg: dm.DivisionConfig):
-    """Assignment + mean inertia under fixed centroids (no update)."""
+def _assign_and_inertia(x, c, cfg: dm.DivisionConfig, *, n_total=None,
+                        reduce=None):
+    """Assignment + mean inertia under fixed centroids (no update).
+
+    On a shard, ``reduce`` sums the local inertia over the mesh and
+    ``n_total`` counts every point, not the resident ones.
+    """
+    import jax
     import jax.numpy as jnp
 
     d2 = pairwise_mean_sqdist(x, c, cfg)
-    assign = jnp.argmin(d2, axis=-1)
-    n_pts = jnp.asarray(x.shape[-2], x.dtype)
-    inertia = dm.div(jnp.sum(jnp.min(d2, axis=-1), axis=-1), n_pts, cfg)
+    with jax.named_scope(SCOPE_ASSIGN):
+        assign = jnp.argmin(d2, axis=-1)
+        if n_total is None:
+            n_total = jnp.asarray(x.shape[-2], x.dtype)
+        total = jnp.sum(jnp.min(d2, axis=-1), axis=-1)
+        if reduce is not None:
+            total = reduce(total)
+        inertia = dm.div(total, n_total, cfg)
     return d2, assign, inertia
 
 
@@ -137,18 +159,20 @@ def lloyd_step(x, c, cfg: dm.DivisionConfig = dm.TAYLOR):
 
     k = c.shape[-2]
     d2, assign, inertia = _assign_and_inertia(x, c, cfg)
-    onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)        # (..., N, K)
-    counts = jnp.sum(onehot, axis=-2)                        # (..., K)
-    sums = _cluster_sums(onehot, x)                          # (..., K, D)
-    # Empty clusters: divide by max(count, 1) — not by the raw count — so
-    # the 0/0 lane never exists even in exact mode, whose d(a/b) = 1/b
-    # cotangent would turn into 0 * inf = nan under the where mask below
-    # (the approximate modes survive via attach_grad's finite-lane masking,
-    # exact mode has no such guard). The masked lanes keep the previous
-    # centroid — the workload-level analogue of the FTZ edge contract.
-    occupied = counts[..., :, None] > 0
-    new_c = dm.div(sums, jnp.maximum(counts, 1)[..., :, None], cfg)
-    new_c = jnp.where(occupied, new_c, c)
+    with jax.named_scope(SCOPE_UPDATE):
+        onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)    # (..., N, K)
+        counts = jnp.sum(onehot, axis=-2)                    # (..., K)
+        sums = _cluster_sums(onehot, x)                      # (..., K, D)
+        # Empty clusters: divide by max(count, 1) — not by the raw count —
+        # so the 0/0 lane never exists even in exact mode, whose
+        # d(a/b) = 1/b cotangent would turn into 0 * inf = nan under the
+        # where mask below (the approximate modes survive via attach_grad's
+        # finite-lane masking, exact mode has no such guard). The masked
+        # lanes keep the previous centroid — the workload-level analogue of
+        # the FTZ edge contract.
+        occupied = counts[..., :, None] > 0
+        new_c = dm.div(sums, jnp.maximum(counts, 1)[..., :, None], cfg)
+        new_c = jnp.where(occupied, new_c, c)
     return new_c, assign, inertia
 
 
@@ -187,7 +211,8 @@ def kmeans(x, k: Optional[int] = None, *, cfg: dm.DivisionConfig = dm.TAYLOR,
     centroids, trace = jax.lax.scan(step, init, None, length=n_iters)
     # Final assignment/inertia under the converged centroids — evaluation
     # only, no discarded centroid update.
-    _, assign, inertia = _assign_and_inertia(x, centroids, cfg)
+    with jax.named_scope(SCOPE_FINAL):
+        _, assign, inertia = _assign_and_inertia(x, centroids, cfg)
     return KMeansResult(centroids=centroids, assignments=assign,
                         inertia=inertia, inertia_trace=trace)
 
@@ -252,45 +277,46 @@ def kmeans_sharded(x, k: Optional[int] = None, *,
 
     from jax.sharding import PartitionSpec as P
 
+    def assign_global(xl, c):
+        return _assign_and_inertia(
+            xl, c, cfg, n_total=n_total,
+            reduce=lambda v: jax.lax.psum(v, axes))
+
     def body(xl, c0):
         # xl: (N / n_shards, D) resident points; c0: replicated (K, D).
         with shr.suspend_mesh():
             def step(c, _):
-                d2 = pairwise_mean_sqdist(xl, c, cfg)
-                assign = jnp.argmin(d2, axis=-1)
-                onehot = jax.nn.one_hot(assign, kk, dtype=xl.dtype)
-                # Global reduction BEFORE the divide: the unit sees the
-                # whole cluster's sum/count, not a shard's slice of it.
-                # Counts are integer-valued f32 (exact up to 2^24), so the
-                # psum order cannot move them; the sums are reduced in the
-                # canonical block order when the layout allows (an
-                # order-fixed psum: gather the block partials in shard
-                # order, then one left-to-right sum on every device).
-                counts = jax.lax.psum(jnp.sum(onehot, axis=-2), axes)
-                if blocked:
-                    parts = _block_cluster_sums(
-                        onehot, xl, _SUM_BLOCKS // n_shards)
-                    parts = jax.lax.all_gather(parts, axes, axis=0,
-                                               tiled=True)
-                    sums = _ordered_block_sum(parts)
-                else:
-                    sums = jax.lax.psum(
-                        jnp.einsum("nk,nd->kd", onehot, xl,
-                                   precision=_F32_DOT), axes)
-                inertia = dm.div(
-                    jax.lax.psum(jnp.sum(jnp.min(d2, axis=-1)), axes),
-                    n_total, cfg)
-                occupied = counts[:, None] > 0
-                new_c = dm.div(sums, jnp.maximum(counts, 1)[:, None], cfg)
-                new_c = jnp.where(occupied, new_c, c)
+                _, assign, inertia = assign_global(xl, c)
+                with jax.named_scope(SCOPE_UPDATE):
+                    onehot = jax.nn.one_hot(assign, kk, dtype=xl.dtype)
+                    # Global reduction BEFORE the divide: the unit sees the
+                    # whole cluster's sum/count, not a shard's slice of it.
+                    # Counts are integer-valued f32 (exact up to 2^24), so
+                    # the psum order cannot move them; the sums are reduced
+                    # in the canonical block order when the layout allows
+                    # (an order-fixed psum: gather the block partials in
+                    # shard order, then one left-to-right sum on every
+                    # device).
+                    counts = jax.lax.psum(jnp.sum(onehot, axis=-2), axes)
+                    if blocked:
+                        parts = _block_cluster_sums(
+                            onehot, xl, _SUM_BLOCKS // n_shards)
+                        parts = jax.lax.all_gather(parts, axes, axis=0,
+                                                   tiled=True)
+                        sums = _ordered_block_sum(parts)
+                    else:
+                        sums = jax.lax.psum(
+                            jnp.einsum("nk,nd->kd", onehot, xl,
+                                       precision=_F32_DOT), axes)
+                    occupied = counts[:, None] > 0
+                    new_c = dm.div(sums, jnp.maximum(counts, 1)[:, None],
+                                   cfg)
+                    new_c = jnp.where(occupied, new_c, c)
                 return new_c, inertia
 
             centroids, trace = jax.lax.scan(step, c0, None, length=n_iters)
-            d2 = pairwise_mean_sqdist(xl, centroids, cfg)
-            assign = jnp.argmin(d2, axis=-1)
-            inertia = dm.div(
-                jax.lax.psum(jnp.sum(jnp.min(d2, axis=-1)), axes),
-                n_total, cfg)
+            with jax.named_scope(SCOPE_FINAL):
+                _, assign, inertia = assign_global(xl, centroids)
         return centroids, assign, inertia, trace
 
     pts = P(axes, None)
