@@ -107,6 +107,10 @@ TAYLOR = DivisionConfig(mode="taylor")
 # the compiled program's op metadata and in a device trace. A scope is
 # compile-time metadata only: it adds no operation.
 SCOPE_PREFIX = "dm."
+# Nested in ``dm.div`` around the launch that divides a plane by one number
+# without broadcasting it (``kernels.ops.tsdiv_divide_uniform``). A path of
+# ``dm.div``, not an entry of its own: its name extends the entry's.
+SCOPE_UNIFORM = SCOPE_PREFIX + "div.uniform_divisor"
 
 
 def _scoped(fn):
@@ -191,21 +195,33 @@ def div(a, b, cfg: DivisionConfig = TAYLOR):
         s = fpparts.sign_product(jnp, aj, bj)
         return fpparts.div_edges(jnp, q, aj, bj, jnp.abs(aj), jnp.abs(bj), s)
     if cfg.mode in ("taylor_pallas", "goldschmidt_pallas"):
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops as kops
 
-        aj, bj = jnp.broadcast_arrays(jnp.asarray(a), jnp.asarray(b))
-        # Promote mixed operands up front (as a * recip(b) would have): the
-        # kernel wrapper returns its first argument's dtype.
-        ct = jnp.promote_types(aj.dtype, bj.dtype)
-        aj, bj = aj.astype(ct), bj.astype(ct)
-        if kops.pallas_applicable(aj) and kops.pallas_applicable(bj):
-            sched = (cfg.schedule if cfg.mode == "taylor_pallas"
-                     else "goldschmidt")
-            return kops.tsdiv_divide(aj, bj, n_iters=cfg.n_iters,
-                                     precision_bits=cfg.precision_bits,
-                                     schedule=sched)
+        sched = cfg.schedule if cfg.mode == "taylor_pallas" else "goldschmidt"
+        aj, bj = jnp.asarray(a), jnp.asarray(b)
+        if bj.size == 1 and aj.ndim >= 2 and bj.ndim <= aj.ndim:
+            # A plane over one number (K-Means' 1/D): the kernel takes the
+            # divisor as it is, no plane of one repeated number.
+            ct = jnp.promote_types(aj.dtype, bj.dtype)
+            aj, bj = aj.astype(ct), bj.astype(ct)
+            if kops.pallas_applicable(aj) and kops.pallas_applicable(bj):
+                with jax.named_scope(SCOPE_UNIFORM):
+                    return kops.tsdiv_divide_uniform(
+                        aj, bj, n_iters=cfg.n_iters,
+                        precision_bits=cfg.precision_bits, schedule=sched)
+        else:
+            aj, bj = jnp.broadcast_arrays(aj, bj)
+            # Promote mixed operands up front (as a * recip(b) would have):
+            # the kernel wrapper returns its first argument's dtype.
+            ct = jnp.promote_types(aj.dtype, bj.dtype)
+            aj, bj = aj.astype(ct), bj.astype(ct)
+            if kops.pallas_applicable(aj) and kops.pallas_applicable(bj):
+                return kops.tsdiv_divide(aj, bj, n_iters=cfg.n_iters,
+                                         precision_bits=cfg.precision_bits,
+                                         schedule=sched)
     if cfg.mode in ("goldschmidt", "goldschmidt_pallas"):
         # Goldschmidt's hallmark: the numerator rides the F-multiplies.
         return goldschmidt.divide(a, b, cfg.table, iters=cfg.gs_iters,

@@ -52,8 +52,9 @@ from __future__ import annotations
 import numpy as np
 
 __all__ = [
-    "two_product", "tree_sum", "sign_product", "decompose_div", "ldexp2",
-    "recombine_div", "div_edges", "refine_quotient", "recombine_recip",
+    "two_product", "veltkamp_split", "tree_sum", "sign_product",
+    "decompose_div", "ldexp2", "recombine_div", "div_edges",
+    "refine_quotient", "recombine_recip",
     "jnp_divide", "jnp_reciprocal", "jnp_rsqrt", "split_f32", "repack_f32",
     "bit_divide", "bit_reciprocal", "UNDERFLOW_POLICIES",
     "F32_SIGN", "F32_MAG_MASK", "F32_EXP_MASK", "F32_MAN_MASK",
@@ -104,23 +105,27 @@ def sign_product(xp, a, b):
             * xp.copysign(xp.asarray(1.0, b.dtype), b))
 
 
-def two_product(a, b):
+def veltkamp_split(x):
+    """(hi, lo) with x == hi + lo, each half fitting in half the mantissa:
+    the split by 2^ceil(prec/2) + 1 (f32 -> 4097, f64 -> 2^27 + 1)."""
+    prec = np.finfo(np.dtype(x.dtype)).nmant + 1
+    t = float(2 ** ((prec + 1) // 2) + 1) * x
+    hi = t - (t - x)
+    return hi, x - hi
+
+
+def two_product(a, b, b_split=None):
     """Error-free transform of a product: returns (p, e) with a*b == p + e.
 
-    Veltkamp-split both operands with the factor 2^ceil(prec/2) + 1
-    (f32 -> 4097, f64 -> 2^27 + 1) and recover the rounding error of the
-    p-bit product. Works under FMA contraction too — a contracted
-    ``ah*bh - p`` is the exact error term.
+    Veltkamp-split both operands (:func:`veltkamp_split`) and recover the
+    rounding error of the p-bit product. Works under FMA contraction too —
+    a contracted ``ah*bh - p`` is the exact error term. ``b_split`` is
+    ``veltkamp_split(b)`` computed beforehand, for a ``b`` shared by many
+    products.
     """
     p = a * b
-    prec = np.finfo(np.dtype(a.dtype)).nmant + 1
-    c = float(2 ** ((prec + 1) // 2) + 1)
-    ta = c * a
-    ah = ta - (ta - a)
-    al = a - ah
-    tb = c * b
-    bh = tb - (tb - b)
-    bl = b - bh
+    ah, al = veltkamp_split(a)
+    bh, bl = veltkamp_split(b) if b_split is None else b_split
     e = ((ah * bh - p) + ah * bl + al * bh) + al * bl
     return p, e
 
@@ -433,7 +438,7 @@ def bit_reciprocal(x, mantissa_fn, underflow: str = "gradual"):
     return jnp.where(x_nan, jnp.float32(np.nan), r)
 
 
-def refine_quotient(q0, man_a, man_b, rman):
+def refine_quotient(q0, man_a, man_b, rman, b_split=None):
     """Markstein correcting step: q = q0 + rman * (man_a - q0*man_b).
 
     The remainder is computed error-free: two_product gives q0*man_b as
@@ -442,8 +447,9 @@ def refine_quotient(q0, man_a, man_b, rman):
     few thousand ULPs the corrected quotient lands within ~1 ULP of
     man_a/man_b — this is the float emulation of the hardware unit's
     full-width final multiplier (Fig. 7), whose 2p-bit product is rounded
-    exactly once.
+    exactly once. ``b_split`` is ``veltkamp_split(man_b)`` where the caller
+    has it already (see :func:`two_product`).
     """
-    p, e = two_product(q0, man_b)
+    p, e = two_product(q0, man_b, b_split)
     res = (man_a - p) - e
     return q0 + res * rman

@@ -6,6 +6,8 @@ constants, which vectorizes perfectly on the VPU), no data-dependent shapes.
 """
 from __future__ import annotations
 
+from typing import NamedTuple, Tuple
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -93,6 +95,101 @@ def _pow2(k: jax.Array) -> jax.Array:
         (jnp.clip(k + 127, 1, 254).astype(jnp.uint32)) << 23, jnp.float32)
 
 
+class Divisor(NamedTuple):
+    """The divisor's half of the divide datapath: every value that depends
+    on b alone (:func:`divisor_f32_bits`).
+
+    ``mant`` holds the mantissa refinement's divisor terms: for the Taylor
+    schedules ``(rman, man_b, man_b_hi, man_b_lo)`` — the series
+    reciprocal of the mantissa, the mantissa and its Veltkamp halves for
+    the Markstein step; for ``schedule="goldschmidt"`` ``(y0, r_0, ...,
+    r_{k-1})`` — the seed and the residual sequence the numerator rides.
+    """
+    bbits: jax.Array
+    eb: jax.Array
+    mant: Tuple[jax.Array, ...]
+    b_zero: jax.Array
+    b_inf: jax.Array
+    b_nan: jax.Array
+
+
+def divisor_f32_bits(b: jax.Array, table: SeedTable, n: int,
+                     schedule: str) -> Divisor:
+    """Unpack b, seed and refine its mantissa, classify its edges.
+
+    A kernel dividing by one divisor runs this once per tile on one vreg
+    holding it and tiles the result; :func:`divide_f32_bits` runs it on the
+    whole divisor plane. Either way the operations on each lane are the
+    same, so the quotients agree bit for bit.
+    """
+    from repro.core import fpparts
+    from repro.core.taylor import exact_residual
+
+    bbits = jax.lax.bitcast_convert_type(b, jnp.uint32)
+    eb = ((bbits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
+    bmant = bbits & F32_MAN_MASK
+    man_b = jax.lax.bitcast_convert_type(bmant | F32_ONE_BITS, jnp.float32)
+    y0 = seed_ladder(man_b, table)
+    if schedule == "goldschmidt":
+        from repro.core.goldschmidt import iters_for_terms
+
+        # goldschmidt._refine's residual sequence: r_0 = 1 - man_b*y0
+        # (exact), r_{i+1} = r_i^2 — none of it touches the numerator.
+        r, rs = exact_residual(man_b, y0), []
+        for _ in range(iters_for_terms(n)):
+            rs.append(r)
+            r = r * r
+        mant = (y0, *rs)
+    else:
+        rman = series_refine(y0, man_b, n, schedule)
+        mant = (rman, man_b, *fpparts.veltkamp_split(man_b))
+    b_inf = (eb == 255) & (bmant == 0)
+    b_nan = (eb == 255) & (bmant != 0)
+    return Divisor(bbits, eb, mant, eb == 0, b_inf, b_nan)
+
+
+def divide_by_f32_bits(a: jax.Array, d: Divisor, schedule: str) -> jax.Array:
+    """a / b given b's half of the datapath ``d`` (:func:`divisor_f32_bits`):
+    the numerator's unpack, the mantissa quotient, the recombine and the
+    edge contract. ``d``'s arrays broadcast against ``a``."""
+    from repro.core import fpparts
+
+    abits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+    sign = (abits ^ d.bbits) & F32_SIGN
+    ea = ((abits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
+    amant = abits & F32_MAN_MASK
+    man_a = jax.lax.bitcast_convert_type(amant | F32_ONE_BITS, jnp.float32)
+    if schedule == "goldschmidt":
+        # goldschmidt._refine(man_a * y0, man_b, y0, k): N += N * r_i.
+        y0, *rs = d.mant
+        q_man = man_a * y0
+        for r in rs:
+            q_man = q_man + q_man * r
+    else:
+        rman, man_b, bh, bl = d.mant
+        q_man = fpparts.refine_quotient(man_a * rman, man_a, man_b, rman,
+                                        b_split=(bh, bl))
+    # q = q_man * 2^(ea-eb), split so each factor is a normal power of two.
+    de = ea - d.eb                                  # biased diff == unbiased diff
+    h = de >> 1
+    q = (q_man * _pow2(h)) * _pow2(de - h)
+    # FTZ: quotients below the normal range flush to zero (sign added below).
+    q = jnp.where(jnp.abs(q) < jnp.float32(2.0 ** -126), jnp.float32(0.0), q)
+    # Edge classes on the FTZ'd operands: exp 0 => zero, exp 255 => inf/nan.
+    a_zero = ea == 0
+    a_inf = (ea == 255) & (amant == 0)
+    q = jnp.where(d.b_zero, jnp.float32(np.inf), q)          # x/0 -> inf
+    q = jnp.where(a_zero, jnp.float32(0.0), q)               # 0/x -> 0
+    q = jnp.where(a_inf, jnp.float32(np.inf), q)             # inf/x -> inf
+    q = jnp.where(d.b_inf, jnp.float32(0.0), q)              # x/inf -> 0
+    q = jnp.where(a_zero & d.b_zero, jnp.float32(np.nan), q) # 0/0
+    q = jnp.where(a_inf & d.b_inf, jnp.float32(np.nan), q)   # inf/inf
+    qbits = jax.lax.bitcast_convert_type(q, jnp.uint32) | sign
+    q = jax.lax.bitcast_convert_type(qbits, jnp.float32)
+    a_nan = (ea == 255) & (amant != 0)
+    return jnp.where(a_nan | d.b_nan, jnp.float32(np.nan), q)
+
+
 def divide_f32_bits(a: jax.Array, b: jax.Array, table: SeedTable, n: int,
                     schedule: str) -> jax.Array:
     """Fused exponent-separated a/b: the full divide datapath in one kernel.
@@ -106,47 +203,12 @@ def divide_f32_bits(a: jax.Array, b: jax.Array, table: SeedTable, n: int,
     intermediate scale never under/overflows while a/b is representable.
     FTZ semantics as elsewhere: denormal operands are treated as zeros and
     denormal quotients flush to +-0.
+
+    The datapath is the divisor's half (:func:`divisor_f32_bits`) followed
+    by the numerator's (:func:`divide_by_f32_bits`), here both lane by lane.
     """
-    from repro.core import fpparts
-
-    abits = jax.lax.bitcast_convert_type(a, jnp.uint32)
-    bbits = jax.lax.bitcast_convert_type(b, jnp.uint32)
-    sign = (abits ^ bbits) & F32_SIGN
-    ea = ((abits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
-    eb = ((bbits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
-    amant = abits & F32_MAN_MASK
-    bmant = bbits & F32_MAN_MASK
-    man_a = jax.lax.bitcast_convert_type(amant | F32_ONE_BITS, jnp.float32)
-    man_b = jax.lax.bitcast_convert_type(bmant | F32_ONE_BITS, jnp.float32)
-    y0 = seed_ladder(man_b, table)
-    if schedule == "goldschmidt":
-        from repro.core.goldschmidt import _refine, iters_for_terms
-
-        q_man = _refine(man_a * y0, man_b, y0, iters_for_terms(n))
-    else:
-        rman = series_refine(y0, man_b, n, schedule)
-        q_man = fpparts.refine_quotient(man_a * rman, man_a, man_b, rman)
-    # q = q_man * 2^(ea-eb), split so each factor is a normal power of two.
-    de = ea - eb                                    # biased diff == unbiased diff
-    h = de >> 1
-    q = (q_man * _pow2(h)) * _pow2(de - h)
-    # FTZ: quotients below the normal range flush to zero (sign added below).
-    q = jnp.where(jnp.abs(q) < jnp.float32(2.0 ** -126), jnp.float32(0.0), q)
-    # Edge classes on the FTZ'd operands: exp 0 => zero, exp 255 => inf/nan.
-    a_zero, b_zero = ea == 0, eb == 0
-    a_inf = (ea == 255) & (amant == 0)
-    b_inf = (eb == 255) & (bmant == 0)
-    q = jnp.where(b_zero, jnp.float32(np.inf), q)            # x/0 -> inf
-    q = jnp.where(a_zero, jnp.float32(0.0), q)               # 0/x -> 0
-    q = jnp.where(a_inf, jnp.float32(np.inf), q)             # inf/x -> inf
-    q = jnp.where(b_inf, jnp.float32(0.0), q)                # x/inf -> 0
-    q = jnp.where(a_zero & b_zero, jnp.float32(np.nan), q)   # 0/0
-    q = jnp.where(a_inf & b_inf, jnp.float32(np.nan), q)     # inf/inf
-    qbits = jax.lax.bitcast_convert_type(q, jnp.uint32) | sign
-    q = jax.lax.bitcast_convert_type(qbits, jnp.float32)
-    a_nan = (ea == 255) & (amant != 0)
-    b_nan = (eb == 255) & (bmant != 0)
-    return jnp.where(a_nan | b_nan, jnp.float32(np.nan), q)
+    return divide_by_f32_bits(a, divisor_f32_bits(b, table, n, schedule),
+                              schedule)
 
 
 def rsqrt_f32_bits(x: jax.Array, table: SeedTable, newton_iters: int) -> jax.Array:

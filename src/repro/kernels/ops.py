@@ -89,21 +89,23 @@ def _row_shard_axes(rows: int):
     return mesh, axes
 
 
-def _shard_rows(fn, mesh, axes, n_args: int):
+def _shard_rows(fn, mesh, axes, n_args: int, replicated: int = 0):
     """shard_map a row-tiled 2D kernel launch: dim 0 sharded over ``axes``.
 
     The body receives the per-shard (rows/n, N) block and launches the tiled
     kernel on it directly — grid and block specs are recomputed from the
     local shape, so sharded operands stay resident end to end (zero
     collectives; the conformance for this is pinned in
-    tests/test_sharded_kernels.py). check_vma=False: the elementwise body
-    has no varying-axis types for shard_map's checker to track through the
-    pallas_call.
+    tests/test_sharded_kernels.py). The last ``replicated`` of the
+    ``n_args`` arguments are whole on every device (a uniform divisor).
+    check_vma=False: the elementwise body has no varying-axis types for
+    shard_map's checker to track through the pallas_call.
     """
     from jax.sharding import PartitionSpec as P
 
     spec = P(axes, None)
-    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * n_args,
+    in_specs = (spec,) * (n_args - replicated) + (P(),) * replicated
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=spec, check_vma=False)
 
 
@@ -270,6 +272,57 @@ def _divide_bwd(n_iters, precision_bits, schedule, res, g):
 
 
 tsdiv_divide.defvjp(_divide_fwd, _divide_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def tsdiv_divide_uniform(a, b, n_iters: int = 2, precision_bits: int = 24,
+                         schedule: str = "factored"):
+    """a / b for a rank >= 2 ``a`` and a one-element ``b`` of no higher
+    rank, without broadcasting b (``division_modes.div`` takes this path).
+
+    The tiled kernel gets b as a (1, 1) array and runs the divisor's half
+    of the datapath once per tile (``tsdiv.tsdiv_divide_tiled_2d``), so no
+    plane of one repeated number is written or read. Same bits as
+    :func:`tsdiv_divide` on b broadcast to a's shape. Under a mesh the rows
+    shard as there and b is replicated. Analytic VJP: da = g/b and
+    db = -sum(g*q/b), edge lanes masked as in :func:`tsdiv_divide`.
+    """
+    orig_dtype, shape = a.dtype, a.shape
+    if a.ndim < 2 or b.size != 1 or b.ndim > a.ndim:
+        raise ValueError(
+            f"tsdiv_divide_uniform divides a rank >= 2 array by one number, "
+            f"got {shape} by {b.shape}")
+    if a.size == 0:      # no lanes to launch; keep the shape/dtype contract
+        return jnp.divide(a, b.reshape(())).astype(orig_dtype)
+    rows = int(np.prod(shape[:-1]))
+    a2 = a.astype(jnp.float32).reshape(rows, shape[-1])
+    b2 = b.astype(jnp.float32).reshape(1, 1)
+
+    def launch(al, bl):
+        return tsdiv_k.tsdiv_divide_tiled_2d(
+            al, bl, n_iters=n_iters, precision_bits=precision_bits,
+            schedule=schedule, interpret=INTERPRET)
+
+    info = _row_shard_axes(rows)
+    if info is not None:
+        launch = _shard_rows(launch, *info, n_args=2, replicated=1)
+    return launch(a2, b2).reshape(shape).astype(orig_dtype)
+
+
+def _divide_uniform_fwd(a, b, n_iters, precision_bits, schedule):
+    q = tsdiv_divide_uniform(a, b, n_iters, precision_bits, schedule)
+    return q, (q, b)
+
+
+def _divide_uniform_bwd(n_iters, precision_bits, schedule, res, g):
+    q, b = res
+    rb = tsdiv_recip(b, n_iters, precision_bits, schedule).reshape(())
+    rb = jnp.where(jnp.isfinite(rb), rb, 0.0)
+    qf = jnp.where(jnp.isfinite(q), q, 0.0)
+    return g * rb, jnp.sum(-(g * qf * rb)).reshape(b.shape).astype(b.dtype)
+
+
+tsdiv_divide_uniform.defvjp(_divide_uniform_fwd, _divide_uniform_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

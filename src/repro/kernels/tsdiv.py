@@ -25,6 +25,11 @@ from repro.core.seeds import SeedTable, compute_segments
 from . import common
 
 DEFAULT_BLOCK = (256, 256)
+# The uniform-divisor kernel's block: its per-lane work is half the
+# two-plane kernel's, so the grid's per-step cost weighs more (a K-Means
+# plane on a TPU v5e: 23.1 ms an iteration at (1024, 512), 27.7 at
+# (256, 256), 23.6 at (512, 512)).
+UNIFORM_BLOCK = (1024, 512)
 
 # f32 hardware tile lattice: VREG lane width x sublane count. ops.py's
 # shape-generic wrappers pad to the same lattice (imported from here).
@@ -111,6 +116,32 @@ def _divide_tiled_kernel(a_ref, b_ref, o_ref, *, table: SeedTable, n: int,
     o_ref[...] = common.divide_f32_bits(a, b, table, n, schedule)
 
 
+def _divide_uniform_tiled_kernel(a_ref, b_ref, o_ref, *, table: SeedTable,
+                                 n: int, schedule: str, block):
+    """Fused divide of one (bm, bn) tile of a ragged (M, N) plane by the one
+    divisor in the (1, 1) ``b_ref``.
+
+    The divisor's half of the datapath (unpack, seed ladder, series or
+    residual sequence, Veltkamp split, edge flags) runs once per tile on
+    one vreg holding the divisor, tiled to one strip of SUBLANE rows
+    (Mosaic broadcasts no (1, 1) integer array to a plane). The numerator's
+    half then runs strip by strip, so that its intermediates stay in vector
+    registers: whole-tile intermediates would spill. Lanes past the array
+    edge are not masked: each lane's quotient depends on its own numerator
+    alone, and theirs are dropped on store.
+    """
+    vreg = jnp.broadcast_to(b_ref[...], (SUBLANE, LANE))
+    d = common.divisor_f32_bits(vreg, table, n, schedule)
+    d = jax.tree.map(lambda v: jnp.tile(v, (1, block[1] // LANE)), d)
+
+    def strip(i, carry):
+        rows = pl.ds(pl.multiple_of(i * SUBLANE, SUBLANE), SUBLANE)
+        o_ref[rows, :] = common.divide_by_f32_bits(a_ref[rows, :], d, schedule)
+        return carry
+
+    jax.lax.fori_loop(0, block[0] // SUBLANE, strip, 0)
+
+
 @functools.partial(jax.jit, static_argnames=("n_iters", "precision_bits", "schedule",
                                              "block", "interpret"))
 def tsdiv_recip_2d(x, *, n_iters: int = 2, precision_bits: int = 24,
@@ -182,7 +213,7 @@ def tsdiv_divide_2d(a, b, *, n_iters: int = 2, precision_bits: int = 24,
 @functools.partial(jax.jit, static_argnames=("n_iters", "precision_bits", "schedule",
                                              "block", "interpret"))
 def tsdiv_divide_tiled_2d(a, b, *, n_iters: int = 2, precision_bits: int = 24,
-                          schedule: str = "factored", block=DEFAULT_BLOCK,
+                          schedule: str = "factored", block=None,
                           interpret: bool = True):
     """a / b over an arbitrary f32 (M, N) array — the streaming form.
 
@@ -193,14 +224,31 @@ def tsdiv_divide_tiled_2d(a, b, *, n_iters: int = 2, precision_bits: int = 24,
     a time; non-multiple-of-block shapes are handled by ragged last tiles
     whose dead lanes are masked in-kernel. This is the path
     ``kernels.ops.tsdiv_divide`` takes for rank-2 operands.
+
+    ``b`` is either a plane of ``a``'s shape or, for a plane divided by one
+    number, a (1, 1) array (``kernels.ops.tsdiv_divide_uniform``): that
+    divisor enters the kernel once, as a block whose index never moves, and
+    its half of the datapath runs once per tile instead of once per lane.
+    The quotients are the same bits as dividing by the broadcast plane.
+    ``block`` defaults to ``DEFAULT_BLOCK`` for two planes and to
+    ``UNIFORM_BLOCK`` for a (1, 1) divisor.
     """
     table = compute_segments(n_iters, precision_bits)
+    uniform = b.shape == (1, 1) and a.shape != (1, 1)
+    if block is None:
+        block = UNIFORM_BLOCK if uniform else DEFAULT_BLOCK
     grid, spec, blk = _tiled_grid_spec(a.shape, block)
+    if uniform:
+        body = functools.partial(_divide_uniform_tiled_kernel, block=blk)
+        b_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    else:
+        body = functools.partial(_divide_tiled_kernel, shape=a.shape,
+                                 block=blk)
+        b_spec = spec
     return pl.pallas_call(
-        functools.partial(_divide_tiled_kernel, table=table, n=n_iters,
-                          schedule=schedule, shape=a.shape, block=blk),
+        functools.partial(body, table=table, n=n_iters, schedule=schedule),
         grid=grid,
-        in_specs=[spec, spec],
+        in_specs=[spec, b_spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
         interpret=interpret,

@@ -98,6 +98,62 @@ def test_sharded_divide_bit_identity_and_no_collectives():
     _run(DIVIDE_SNIPPET, "DIVIDE8 OK")
 
 
+UNIFORM_SNIPPET = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp
+from repro.core import division_modes as dm
+from repro.kernels import ops
+from repro.launch.mesh import make_host_mesh
+from repro.launch import roofline as rl
+from repro.sharding import rules as shr
+from repro.workloads import kmeans as km
+
+mesh = make_host_mesh()
+assert mesh.devices.size == 4
+
+# The plane over one number: rows shard, the divisor is replicated.
+rows, cols = 1992, 300
+a = jax.random.uniform(jax.random.PRNGKey(0), (rows, cols), jnp.float32,
+                       0.1, 1e4)
+b = jnp.float32(42.0)
+ref = ops.tsdiv_divide_uniform(a, b)              # no mesh: plain launch
+a_s = jax.device_put(a, shr.data_sharding(mesh, 2, batch_size=rows))
+with shr.use_mesh(mesh):
+    got = ops.tsdiv_divide_uniform(a_s, b)
+    hlo = jax.jit(ops.tsdiv_divide_uniform).lower(a_s, b).compile().as_text()
+assert bool(jnp.all(got.view(jnp.int32) == ref.view(jnp.int32))), \
+    "sharded uniform divide not bit-identical"
+assert not rl.parse_collectives(hlo, 4)["ops"], "uniform divide has collectives"
+
+# Data-parallel K-Means through the fused kernels: the plane divide in the
+# shard body takes the divisor unbroadcast, and the assignments are the
+# unsharded run's.
+cfg = dm.DivisionConfig(mode="taylor_pallas")
+N, D, K, ITERS = 4 * 1024, 8, 16, 2
+x = km.make_blobs(jax.random.PRNGKey(1), N, D, K)
+init = jnp.take(x, jnp.arange(K) * (N // K), axis=0)
+one = km.kmeans(x, cfg=cfg, n_iters=ITERS, init=init)
+x_s = jax.device_put(x, shr.data_sharding(mesh, 2, batch_size=N))
+with shr.use_mesh(mesh):
+    f = jax.jit(lambda xx, ii: km.kmeans_sharded(
+        xx, cfg=cfg, n_iters=ITERS, init=ii).assignments)
+    four = f(x_s, init)
+    text = f.lower(x_s, init).compile().as_text()
+assert bool(jnp.all(one.assignments == four)), \
+    "sharded K-Means assignments differ from the unsharded run"
+assert dm.SCOPE_UNIFORM in text, "the shard body broadcast its divisor"
+print("UNIFORM4 OK")
+"""
+
+
+def test_sharded_uniform_divisor():
+    """A plane over one number on four devices: sharded == single-device
+    bitwise with the divisor replicated and no collectives; ``kmeans_sharded``
+    through the fused kernels assigns as the unsharded run does."""
+    _run(UNIFORM_SNIPPET, "UNIFORM4 OK")
+
+
 RECIP_RSQRT_SNIPPET = f"""
 import os
 {_ENV8}

@@ -86,6 +86,7 @@ PLANE = (4096, 1024)
 @pytest.mark.parametrize("name,fn,shapes", [
     ("recip", ops.tsdiv_recip, [(PLANE, F32)]),
     ("divide", ops.tsdiv_divide, [(PLANE, F32), (PLANE, F32)]),
+    ("divide_uniform", ops.tsdiv_divide_uniform, [(PLANE, F32), ((1, 1), F32)]),
     ("rsqrt", ops.tsdiv_rsqrt, [(PLANE, F32)]),
     ("softmax", ops.softmax, [((8, 12, 512, 512), F32)]),
     # 1500 lanes pad to 1536, and the row sum's tree pads those to 2048.
@@ -192,7 +193,11 @@ def _kmeans_call(n_iters):
 def test_kmeans_scopes_for_v5e(compiled_text):
     """Every operation of the Lloyd call on the chip sits in a phase's
     scope; every divide kernel in ``dm.div``, under the name the benchmark
-    matches; the (N, K) divisor broadcast in ``dm.div`` beside it."""
+    matches; the (N, K) plane's divides take the divisor unbroadcast, in
+    the uniform-divisor scope, and no (N, K) plane is broadcast in
+    ``dm.div``."""
+    from repro.core import division_modes as dm
+
     n, k = 8192, 500
     text = compiled_text(_kmeans_call(2), ((n, 42), F32), ((k, 42), F32))
     assert unscoped(text) == []
@@ -201,16 +206,24 @@ def test_kmeans_scopes_for_v5e(compiled_text):
     launches = [(nm, ln) for nm, op, ln in ops_
                 if op == "custom-call" and "tpu_custom_call" in ln]
     assert len(launches) >= 3      # plane and centroid divides, final plane
-    for name, _ in launches:
+    uniform = (re.escape(dm.SCOPE_UNIFORM),)
+    planes = []
+    for name, line in launches:
         assert name.startswith("%tsdiv_"), name
         assert bench_kernels.is_tsdiv(name), name
         assert scopes.under(paths[name], ("dm\\.div",)), paths[name]
-    planes = [nm for nm, op, ln in ops_ if op == "broadcast"
-              and f"= f32[{n},{k}]" in ln]
-    assert planes
-    for name in planes:
-        assert scopes.under(paths[name], ("dm\\.div",)), paths[name]
-        assert scopes.under(paths[name], (scopes.DISTANCE,)), paths[name]
+        if f"= f32[{n},{k}]" in line:
+            planes.append(name)
+            assert scopes.under(paths[name], uniform), paths[name]
+            assert scopes.under(paths[name], (scopes.DISTANCE,)), paths[name]
+            assert f"f32[{n},{k}]" in line.split("custom-call(", 1)[1]
+            assert "f32[1,1]" in line.split("custom-call(", 1)[1], line
+        else:
+            assert not scopes.under(paths[name], uniform), paths[name]
+    assert len(planes) == 2        # the scan's plane and the final one
+    broadcasts = [paths.get(nm, "") for nm, op, ln in ops_
+                  if op == "broadcast" and f"= f32[{n},{k}]" in ln]
+    assert not [p for p in broadcasts if scopes.under(p, ("dm\\.div",))]
 
 
 def test_kmeans_sharded_scopes_for_v5e_mesh(topo, compiled_text):
