@@ -17,8 +17,15 @@ token's time is when ``serve`` appends it to its request.
 ``correct``: after the window, requests it finished are drawn from the
 seed (the longest among them), and the plain float32 forward of
 bench/reference/granite.py runs over each prompt with its served tokens.
-The number compared is the widest gap by which a served token's reference
-logit lies below the reference's best at that position.
+``logit_gap`` is the widest gap by which a served token's reference logit
+lies below the reference's best at that position. The cache is compared
+too: at the window's close the key and value rows that the engine's cache
+holds for requests in flight (the one with the most served tokens, and
+more drawn from the seed) are copied off the chip, and the reference
+computes the same rows from the request's prompt and served tokens.
+``cache_gap`` is the widest distance between a row of the engine and the
+reference's row, over the larger of that row's norm and the median row
+norm of its layer; a step that leaves the cache unwritten reads 1.
 """
 from __future__ import annotations
 
@@ -32,7 +39,10 @@ from bench.lib import device, seeds, stats, traffic
 from bench.lib.context import Check, Ctx, Outcome
 from bench.lib.window import Window
 
-CHECKS = ("logit_gap",)
+CHECKS = ("logit_gap", "cache_gap")
+
+# requests in flight at the close whose cache rows are compared
+CACHE_REQUESTS = 3
 
 
 class StopWindow(Exception):
@@ -159,6 +169,7 @@ class Driver:
         self.prefills: List[tuple] = []      # (start, end, padded length)
         self.decodes: List[tuple] = []       # (start, end)
         self.t_open = self.t_close = None
+        self.at_close = None                 # (cache, tokens, pos)
 
     def prefill(self, tokens, lengths):
         t = time.perf_counter()
@@ -174,6 +185,7 @@ class Driver:
             elif (self.t_open is not None
                   and time.perf_counter() - self.t_open >= self.seconds):
                 self.t_close = self.window.close()
+                self.at_close = (cache, tokens, pos)
                 raise StopWindow
         t = time.perf_counter()
         with self.annotate("bench.decode"):
@@ -254,6 +266,7 @@ def window_stats(reqs, drv: Driver, slots: int):
     e2e = {"serve_tok_per_s": (gen + prompt) / window_s}
     if itl:
         e2e["itl_p95_ms"] = stats.percentile(itl, 95) * 1e3
+        e2e["itl_p90_ms"] = stats.percentile(itl, 90) * 1e3
         e2e["itl_p50_ms"] = stats.percentile(itl, 50) * 1e3
     if ttft:    # printed, not a metric: too few admissions for a tail
         e2e["ttft_p95_ms"] = stats.percentile(ttft, 95) * 1e3
@@ -304,6 +317,76 @@ def gaps(weights, cfg, reqs, dot: str = "highest"):
     return out
 
 
+def closing_rows(reqs, drv: Driver, n: int, seed: int):
+    """Requests in flight at the window's close, the one with the most
+    served tokens and ``n - 1`` more drawn from the seed, each with the
+    key and value rows its slot's cache holds there, as host arrays
+    (layers, rows, kv heads, head dim). A request's rows are those of its
+    prompt and served tokens but the last, which the step the close cut
+    off was to write."""
+    import jax
+    import numpy as np
+
+    cache, tokens, pos = drv.at_close
+    tokens, pos = np.asarray(tokens)[:, 0], np.asarray(pos)
+    slot_of = {}
+    for r in reqs:
+        if r.done or not r.out:
+            continue
+        p = len(r.tokens) + len(r.out) - 1
+        match = np.flatnonzero((pos == p) & (tokens == r.out[-1]))
+        if len(match) == 1:
+            slot_of[id(r)] = (r, int(match[0]))
+    live = sorted(slot_of.values(), key=lambda rs: -len(rs[0].out))
+    if not live:
+        return []
+    rest = list(range(1, len(live)))
+    seeds.rng(seed, seeds.STREAM_CACHE).shuffle(rest)
+    chosen = [live[0]] + [live[j] for j in rest[:n - 1]]
+    (group,) = cache["groups"]
+    (layer,) = group["layers"]
+    kv = layer["attn"]
+    stacked = kv["k"].ndim == 5        # (layers, slots, rows, kv, hd)
+    take = jax.jit(lambda a, i: jax.lax.dynamic_index_in_dim(
+        a, i, axis=1 if stacked else 0, keepdims=False))
+    out = []
+    for r, slot in chosen:
+        p = len(r.tokens) + len(r.out) - 1
+        rows = []
+        for name in ("k", "v"):
+            a = np.asarray(jax.device_get(take(kv[name], slot)))
+            a = a if stacked else a[None]
+            rows.append(a[:, :p].astype(np.float32))
+        out.append((r, rows[0], rows[1]))
+    return out
+
+
+def cache_gaps(weights, cfg, held, dot: str = "highest"):
+    """Per request of ``closing_rows``, the widest distance between a key
+    or value row of the engine (``dot="highest"``) or of the control (any
+    other ``dot``) and the reference's row, over the larger of the
+    reference row's norm and its layer's median row norm."""
+    import numpy as np
+
+    from bench.reference import granite
+
+    out = []
+    for r, k, v in held:
+        seq = list(r.tokens) + list(r.out[:-1])
+        ref = granite.kv_at(weights, seq, theta=cfg.rope_theta,
+                            eps=cfg.norm_eps)
+        got = (k, v) if dot == "highest" else granite.kv_at(
+            weights, seq, theta=cfg.rope_theta, eps=cfg.norm_eps, dot=dot)
+        worst = 0.0
+        for a, b in zip(got, ref):
+            norm = np.linalg.norm(b, axis=(-2, -1))         # (layers, rows)
+            floor = np.median(norm, axis=1, keepdims=True)
+            dist = np.linalg.norm(a - b, axis=(-2, -1))
+            worst = max(worst, float(np.max(dist / np.maximum(norm, floor))))
+        out.append(worst)
+    return out
+
+
 def setup(ctx: Ctx):
     """(cfg, weights, engine, slots, programs, phases): the weights drawn,
     the engine built, its programs checked and warmed up; the checked
@@ -316,6 +399,9 @@ def setup(ctx: Ctx):
     cj, mix = ctx.cell.config, ctx.cell.traffic
     phases = {} if ctx.t_chips is None else {"chips": ctx.t_chips - ctx.t0}
     cfg = model_config(cj)
+    if int(mix["max_len"]) > int(cj["max_position_embeddings"]):
+        raise ValueError(f"max_len {mix['max_len']} is past the model's "
+                         f"context of {cj['max_position_embeddings']}")
     one = jax.sharding.SingleDeviceSharding(ctx.devices[0])
     params = jax.block_until_ready(make_weights(cfg, ctx.seed, one))
     phases["weights"] = time.perf_counter() - ctx.t0
@@ -384,15 +470,18 @@ def run(ctx: Ctx) -> Outcome:
     del programs
     e2e, work = window_stats(reqs, drv, int(mix["clients"]))
     e2e["setup_s"] = t0 - ctx.t0
+    held = closing_rows(reqs, drv, CACHE_REQUESTS, ctx.seed)
     del drv                     # the engine, its cache and programs
     gc.collect()
 
     picked = pick(reqs, t0, t1, int(mix["check_requests"]), ctx.seed)
     weights = reference_weights(params, cfg)
-    worst = max(gaps(weights, cfg, picked)) if picked else math.nan
+    worst = {"logit_gap": gaps(weights, cfg, picked),
+             "cache_gap": cache_gaps(weights, cfg, held)}
     return Outcome(
         e2e=e2e, work=work,
-        checks=[Check("logit_gap", worst, ctx.limits["logit_gap"])],
+        checks=[Check(k, max(v) if v else math.nan, ctx.limits[k])
+                for k, v in worst.items()],
         attempted=work["admitted"], failed=0, memory_peak_bytes=mem,
         trace=trace,
         notes=[("setup_phases_s", {k: round(v, 3) for k, v in phases.items()}),
@@ -400,6 +489,7 @@ def run(ctx: Ctx) -> Outcome:
                ("compiles_in_window", compiles),
                ("requests_checked", len(picked)),
                ("served_tokens_checked", sum(len(r.out) for r in picked)),
+               ("cache_rows_checked", sum(len(k[0]) for _, k, _ in held)),
                ("itl_samples", work["itl_n"]),
                ("ttft_samples", work["ttft_n"]),
                ("latencies_ms", {k: round(v, 4) for k, v in e2e.items()
@@ -409,14 +499,15 @@ def run(ctx: Ctx) -> Outcome:
 def calibrate(ctx: Ctx, seed_list, control_seeds):
     """Readings for the limits, all in this process: for each seed, fresh
     weights and traffic, a ramp and a window of ``ctx.seconds``, and the
-    gaps of the checked requests against the reference; for the seeds in
-    ``control_seeds`` also the control's gaps (the reference in fp8) at
-    the same positions."""
+    widest gaps of the checked requests and cache rows against the
+    reference; for the seeds in ``control_seeds`` also the control's (the
+    reference in fp8) at the same positions and rows."""
     rows = []
     for seed in seed_list:
         c = dataclasses.replace(ctx, seed=seed)
         cfg, params, reqs, drv, _, _ = serve_window(c, Window(None))
         t0, t1 = drv.t_open, drv.t_close
+        held = closing_rows(reqs, drv, CACHE_REQUESTS, seed)
         del drv
         gc.collect()
         picked = pick(reqs, t0, t1, int(c.cell.traffic["check_requests"]),
@@ -424,10 +515,14 @@ def calibrate(ctx: Ctx, seed_list, control_seeds):
         weights = reference_weights(params, cfg)
         row = {"seed": seed, "requests": len(picked),
                "served": sum(len(r.out) for r in picked),
-               "program": gaps(weights, cfg, picked)}
+               "cache_rows": sum(len(k[0]) for _, k, _ in held),
+               "program": {"logit_gap": max(gaps(weights, cfg, picked)),
+                           "cache_gap": max(cache_gaps(weights, cfg, held))}}
         if seed in control_seeds:
-            row["control"] = gaps(weights, cfg, picked, dot="fp8")
+            row["control"] = {
+                "logit_gap": max(gaps(weights, cfg, picked, dot="fp8")),
+                "cache_gap": max(cache_gaps(weights, cfg, held, dot="fp8"))}
         rows.append(row)
-        del params, weights
+        del params, weights, held
         gc.collect()
     return rows

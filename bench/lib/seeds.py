@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
-STREAM_DATA, STREAM_TRAFFIC, STREAM_WEIGHTS, STREAM_SAMPLE = range(4)
+STREAM_DATA, STREAM_TRAFFIC, STREAM_WEIGHTS, STREAM_SAMPLE, STREAM_CACHE = range(5)
 
 
 def _seq(seed: int, stream: int) -> np.random.SeedSequence:

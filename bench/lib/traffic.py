@@ -1,10 +1,15 @@
 """The general generator that reads a traffic mix (bench/traffic/*.json).
 
-Lengths are drawn so that every seed does the same work: within each block
-of ``block`` requests the lengths are the block's fixed quantiles of the
-named distribution, and the seed only sets their order (prompt and output
-lengths shuffled apart) and the token ids. A window that spans whole blocks
-then meets the same sizes on every seed, in another order.
+Lengths are laid out so that every seed does the same work: within each
+block of ``block`` requests the lengths are the block's fixed quantiles of
+the named distribution, in a fixed low-discrepancy order (``order``), and
+the seed sets only the token ids. A measured window of a closed loop admits
+a dozen or two requests, a fraction of a block; in a seeded order which
+sizes those are changes from seed to seed, and with them the window's work.
+In this order any run of consecutive requests samples the whole
+distribution evenly, and every seed meets the same sizes at the same
+places. Prompt and output lengths follow orders of different steps, so
+that they are paired across the distribution and not rank for rank.
 
 A length spec is a dict:
 
@@ -51,21 +56,33 @@ def shapes(spec: dict, block: int) -> List[int]:
     return sorted(set(block_lengths(spec, block)))
 
 
-def draw(spec: dict, block: int, count: int,
-         rng: np.random.Generator) -> np.ndarray:
+# steps of the two orders: the golden ratio's fraction and sqrt(2) - 1,
+# which are rationally independent, so the pairs fill the square evenly
+PROMPT_STEP = 0.6180339887498949
+OUTPUT_STEP = 0.41421356237309515
+
+
+def order(block: int, step: float) -> np.ndarray:
+    """Quantile indices of a block in the order requests take them:
+    position ``p`` takes the rank of ``p * step`` modulo 1 (a Weyl
+    sequence), so consecutive positions fall far apart in the
+    distribution."""
+    return np.argsort(np.argsort((np.arange(block) * step) % 1.0))
+
+
+def draw(spec: dict, block: int, count: int, step: float) -> np.ndarray:
     """``count`` lengths: whole blocks of the fixed quantiles, each block
-    in an order drawn from ``rng``."""
-    base = np.asarray(block_lengths(spec, block))
-    out = [rng.permutation(base) for _ in range(-(-count // block))]
-    return np.concatenate(out)[:count]
+    in ``order(block, step)``."""
+    base = np.asarray(block_lengths(spec, block))[order(block, step)]
+    return np.resize(base, count)
 
 
 def requests(mix: dict, vocab: int, count: int,
              rng: np.random.Generator):
     """(prompt token lists, output lengths) of ``count`` requests."""
     block = int(mix["block"])
-    plens = draw(mix["prompt"], block, count, rng)
-    olens = draw(mix["output"], block, count, rng)
+    plens = draw(mix["prompt"], block, count, PROMPT_STEP)
+    olens = draw(mix["output"], block, count, OUTPUT_STEP)
     prompts = [rng.integers(0, vocab, int(n), dtype=np.int32).tolist()
                for n in plens]
     return prompts, [int(n) for n in olens]

@@ -96,22 +96,48 @@ def _layer(x, w, pos, *, theta, eps, dot):
     h = _rmsnorm(x, w["mlp_norm"], eps)
     gate = _mm("td,df->tf", h, w["w_gate"], dot)
     up = _mm("td,df->tf", h, w["w_up"], dot)
-    return x + _mm("tf,fd->td", jax.nn.silu(gate) * up, w["w_down"], dot)
+    x = x + _mm("tf,fd->td", jax.nn.silu(gate) * up, w["w_down"], dot)
+    return x, (k, v)
 
 
-@functools.partial(jax.jit, static_argnames=("theta", "eps", "dot"))
-def _forward(weights, tokens, *, theta, eps, dot):
+def _layers(weights, tokens, *, theta, eps, dot, keep_kv):
+    """The residual stream after every layer, and with ``keep_kv`` each
+    layer's rotated keys and values stacked (layers, T, KV, hd)."""
     pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     x = weights["embed"][tokens].astype(jnp.float32)
     layers = {k: v for k, v in weights.items()
               if k not in ("embed", "final_norm", "lm_head")}
 
     def body(x, w):
-        return _layer(x, w, pos, theta=theta, eps=eps, dot=dot), None
+        x, kv = _layer(x, w, pos, theta=theta, eps=eps, dot=dot)
+        return x, (kv if keep_kv else None)
 
-    x, _ = jax.lax.scan(body, x, layers)
+    return jax.lax.scan(body, x, layers)
+
+
+@functools.partial(jax.jit, static_argnames=("theta", "eps", "dot"))
+def _forward(weights, tokens, *, theta, eps, dot):
+    x, _ = _layers(weights, tokens, theta=theta, eps=eps, dot=dot,
+                   keep_kv=False)
     x = _rmsnorm(x, weights["final_norm"], eps)
     return _mm("td,dv->tv", x, weights["lm_head"], dot)
+
+
+@functools.partial(jax.jit, static_argnames=("theta", "eps", "dot"))
+def _kv(weights, tokens, *, theta, eps, dot):
+    return _layers(weights, tokens, theta=theta, eps=eps, dot=dot,
+                   keep_kv=True)[1]
+
+
+def _padded(tokens):
+    """The sequence padded to a multiple of 512 (causal, so padding moves
+    no earlier position), so that few shapes compile."""
+    import numpy as np
+
+    t = len(tokens)
+    toks = np.zeros((-(-t // PAD_TO) * PAD_TO,), np.int32)
+    toks[:t] = tokens
+    return jnp.asarray(toks)
 
 
 def logits_at(weights, tokens, want, *, theta: float, eps: float,
@@ -122,15 +148,23 @@ def logits_at(weights, tokens, want, *, theta: float, eps: float,
     ``weights``: embed (V, d); per layer, stacked on a leading axis,
     attn_norm (d,), wq (d, H, hd), wk/wv (d, KV, hd), wo (H, hd, d),
     mlp_norm (d,), w_gate/w_up (d, F), w_down (F, d); final_norm (d,);
-    lm_head (d, V). The sequence is padded to a multiple of 512 (causal, so
-    padding moves no earlier position), so that few shapes compile.
+    lm_head (d, V).
     """
     import numpy as np
 
-    t = len(tokens)
-    tp = -(-t // PAD_TO) * PAD_TO
-    toks = np.zeros((tp,), np.int32)
-    toks[:t] = tokens
-    out = _forward(weights, jnp.asarray(toks), theta=float(theta),
+    out = _forward(weights, _padded(tokens), theta=float(theta),
                    eps=float(eps), dot=dot)
     return out[jnp.asarray(np.asarray(want, np.int32))]
+
+
+def kv_at(weights, tokens, *, theta: float, eps: float,
+          dot: str = "highest"):
+    """(keys, values) of the sequence ``tokens`` as host float32 arrays
+    (layers, len(tokens), KV, hd): each layer's keys after RoPE and its
+    values, the rows a decode cache holds. ``weights`` as ``logits_at``."""
+    import numpy as np
+
+    k, v = _kv(weights, _padded(tokens), theta=float(theta), eps=float(eps),
+               dot=dot)
+    t = len(tokens)
+    return np.asarray(k)[:, :t], np.asarray(v)[:, :t]

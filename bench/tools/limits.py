@@ -4,7 +4,7 @@
 
 Each number a run compares is read as the run reads it: the worst over
 what one run checks. For serving that is the widest gap over the checked
-requests. A K-Means run checks the first call and one more, so the
+requests and cache rows. A K-Means run checks the first call and one more, so the
 program's reading is the worst over all its calls (the most a run can
 read) and the control's the first call's or the best other call's,
 whichever is worse (the least a run can read). The lower reading is the
@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import math
 import sys
 from pathlib import Path
 
@@ -35,8 +34,6 @@ def run_level(rows, side: str):
         if side not in r:
             continue
         vals = r[side]
-        if isinstance(vals, list):                  # serving: gap per request
-            vals = {"logit_gap": max(vals) if vals else math.nan}
         calls.setdefault(r["seed"], []).append((r.get("call", 0), vals))
     out = {}
     for seed, got in calls.items():

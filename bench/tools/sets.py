@@ -3,11 +3,14 @@ own process, as a check makes them.
 
     python3 bench/tools/sets.py --workload <cell> --seeds 11,12,13,14,15,16 \\
         [--sets 2] [--seconds 10] [--trace-seeds 21,22,23] [--out runs.jsonl]
+    python3 bench/tools/sets.py --summarize runs.jsonl [--sets 2]
 
 Prints, per end-to-end metric, each set's median and spread (interquartile
 distance over the median, by ``statistics.quantiles(values, n=4)``) and
-five times the widest spread, the bound that spread asks for. This process
-never imports JAX, so each child run has the chip to itself.
+five times the widest spread, the bound that spread asks for;
+``--summarize`` prints the same from the runs an earlier call wrote with
+``--out``. This process never imports JAX, so each child run has the chip
+to itself.
 """
 from __future__ import annotations
 
@@ -39,13 +42,21 @@ def one_run(workload: str, seed: int, seconds: float, trace: int) -> dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--workload")
+    ap.add_argument("--seeds", default="")
     ap.add_argument("--sets", type=int, default=2)
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--trace-seeds", default="")
     ap.add_argument("--out", type=Path, default=None)
+    ap.add_argument("--summarize", type=Path, default=None)
     args = ap.parse_args(argv)
+    if args.summarize:
+        summarize([json.loads(line) for line in
+                   args.summarize.read_text().splitlines() if line.strip()],
+                  args.sets)
+        return 0
+    if not (args.workload and args.seeds):
+        ap.error("--workload and --seeds are needed to run sets")
     seeds = [int(s) for s in args.seeds.split(",") if s]
     rows = []
     out = args.out.open("a") if args.out else None
@@ -74,9 +85,13 @@ def main(argv=None) -> int:
         keep(one_run(args.workload, seed, args.seconds, 1))
     if out:
         out.close()
+    summarize(rows, args.sets)
+    return 0
 
+
+def summarize(rows, sets: int):
     widest = {}
-    for s in range(args.sets):
+    for s in range(sets):
         vals = {}
         for row in rows:
             if row.get("set") != s or "result" not in row:
@@ -94,7 +109,6 @@ def main(argv=None) -> int:
     for k, sp in sorted(widest.items()):
         print(f"{k}: widest spread {sp:.5f}, five times {5 * sp:.5f}",
               flush=True)
-    return 0
 
 
 if __name__ == "__main__":

@@ -54,7 +54,8 @@ def test_serving_control_fp8(monkeypatch):
                 "max": 32, "round_to": 1},
         ramp_decode_steps=8, queue_rate_bound=400, ramp_bound_s=5)
     rows = bs.calibrate(ctx_for(cell, 1.5), [5, 6], {5, 6})
-    prog = max(g for r in rows for g in r["program"])
-    ctl = max(g for r in rows for g in r["control"])
-    assert all(r["served"] > 0 for r in rows)
-    assert ctl > max(3 * prog, 1e-3)
+    assert all(r["served"] > 0 and r["cache_rows"] > 0 for r in rows)
+    for name in bs.CHECKS:
+        prog = max(r["program"][name] for r in rows)
+        ctl = min(r["control"][name] for r in rows)
+        assert ctl > max(3 * prog, 1e-3), name

@@ -4,8 +4,10 @@ Each test skips the look for a chip and drives the rest of a run at a
 small size on the CPU, with the cell's own limits, once sound and once for
 each fault the cell can have: a call that returns its state unchanged,
 half of the points left out of the centroid means, an answer altered
-where it is produced, the exchange between chips left out, and a served
-token altered where it is produced.
+where it is produced, the exchange between chips left out; and for each
+serving cell a served token altered in the prefill and in the decode
+step, half of the slots handed the other half's logits, and a decode
+step that leaves its cache unwritten.
 """
 import dataclasses
 import json
@@ -143,45 +145,90 @@ def test_sharded_kmeans_faults_on_four_devices():
 
 # ------------------------------------------------------------------ serving
 
-def serve_cell(monkeypatch):
+# each serving cell at the program's smoke size: the cell's configuration
+# file and mix, widths and lengths scaled down, prompts filling the cache
+# as the cell's do (chat: short prompts, long answers; rag: long prompts)
+SMALL_MIXES = {
+    "granite-8b-chat": dict(
+        clients=4, max_len=96, block=8,
+        prompt={"dist": "lognormal", "median": 24, "sigma": 0.5, "min": 8,
+                "max": 64, "round_to": 8},
+        output={"dist": "lognormal", "median": 8, "sigma": 0.5, "min": 2,
+                "max": 32, "round_to": 1},
+        ramp_decode_steps=8, queue_rate_bound=400, ramp_bound_s=5),
+    "granite-8b-rag": dict(
+        clients=3, max_len=160, block=8,
+        prompt={"dist": "loguniform", "min": 48, "max": 96, "round_to": 8},
+        output={"dist": "loguniform", "min": 16, "max": 48, "round_to": 1},
+        ramp_decode_steps=4, queue_rate_bound=400, ramp_bound_s=5),
+}
+
+
+def serve_cell(monkeypatch, name):
     from repro.configs import get_smoke_config
     from repro.core.division_modes import DivisionConfig
     small = get_smoke_config("granite_8b")
     monkeypatch.setattr(bs, "model_config", lambda c: dataclasses.replace(
         small, division=DivisionConfig(mode=c["division"])))
-    cell = spec.Cell(
-        name="granite-8b.chat40", chips=1, config_name="granite-8b",
-        traffic_name="chat40",
-        config=json.loads((ROOT / "bench/configs/granite-8b.json").read_text()),
-        traffic=spec.load_traffic("chat40"), end_to_end=[], per_layer=[])
+    cell = spec.load_cell(name)
     cell.config = dict(cell.config, hidden_size=small.d_model,
                        num_attention_heads=small.n_heads,
                        num_key_value_heads=small.n_kv_heads,
                        head_dim=small.head_dim, intermediate_size=small.d_ff,
                        vocab_size=small.vocab,
                        num_hidden_layers=small.n_layers)
-    cell.traffic = dict(
-        cell.traffic, clients=4, max_len=96, block=8,
-        prompt={"dist": "lognormal", "median": 24, "sigma": 0.5, "min": 8,
-                "max": 64, "round_to": 8},
-        output={"dist": "lognormal", "median": 8, "sigma": 0.5, "min": 2,
-                "max": 32, "round_to": 1},
-        ramp_decode_steps=8, queue_rate_bound=400, ramp_bound_s=5)
+    cell.traffic = dict(cell.traffic, **SMALL_MIXES[name])
     return cell
 
 
-@pytest.mark.parametrize("fault", [False, True])
-def test_serving_altered_token_is_not_correct(monkeypatch, fault):
+def altered_prefill(real):
+    def call(cfg, params, tokens, **kw):
+        last, cache = real(cfg, params, tokens, **kw)
+        return last.at[:, 0].set(last.max(axis=-1) + 1.0), cache
+    return call
+
+
+def altered_decode(real):
+    def call(cfg, params, cache, tokens, pos):
+        logits, cache = real(cfg, params, cache, tokens, pos)
+        return logits.at[:, 0].set(logits.max(axis=-1) + 1.0), cache
+    return call
+
+
+def half_of_the_slots(real):
+    """Half of the batch left out: those slots get the other half's
+    logits."""
+    def call(cfg, params, cache, tokens, pos):
+        logits, cache = real(cfg, params, cache, tokens, pos)
+        half = logits.shape[0] // 2
+        return logits.at[-half:].set(logits[:half]), cache
+    return call
+
+
+def unchanged_cache(real):
+    """A step that returns its state unchanged: the decode step's logits,
+    but the cache it was given."""
+    def call(cfg, params, cache, tokens, pos):
+        logits, _ = real(cfg, params, cache, tokens, pos)
+        return logits, cache
+    return call
+
+
+SERVE_FAULTS = {"prefill": ("prefill", altered_prefill),
+                "decode_step": ("decode_step", altered_decode),
+                "half_batch": ("decode_step", half_of_the_slots),
+                "unchanged_cache": ("decode_step", unchanged_cache)}
+
+
+@pytest.mark.parametrize("fault", [None, *SERVE_FAULTS])
+@pytest.mark.parametrize("cell", sorted(SMALL_MIXES))
+def test_serving_altered_token_is_not_correct(monkeypatch, cell, fault):
     from repro.serving import engine
-    if fault:
-        real = engine.decode_step
-
-        def altered(cfg, params, cache, tokens, pos):
-            logits, cache = real(cfg, params, cache, tokens, pos)
-            return logits.at[:, 0].set(logits.max(axis=-1) + 1.0), cache
-
-        monkeypatch.setattr(engine, "decode_step", altered)
-    out = bs.run(ctx_for(serve_cell(monkeypatch), seconds=1.5,
-                         limits_of="granite-8b-chat"))
-    assert out.correct == (not fault), [
+    if fault is not None:
+        name, make = SERVE_FAULTS[fault]
+        monkeypatch.setattr(engine, name, make(getattr(engine, name)))
+    out = bs.run(ctx_for(serve_cell(monkeypatch, cell), seed=2200000011,
+                         seconds=1.5))
+    assert out.work["admitted"] > 0
+    assert out.correct == (fault is None), [
         (c.name, c.value, c.limit) for c in out.checks]

@@ -69,6 +69,35 @@ def test_granite_reference_matches_decode_through_the_cache(smoke):
         tok = int(jnp.argmax(logits[0]))
 
 
+def test_granite_reference_keys_and_values_match_the_cache(smoke):
+    """``kv_at`` gives the rows the engine's cache holds after a prefill
+    and decode steps: keys after RoPE, values, every layer."""
+    from repro.serving import ServingEngine
+    from repro.serving.engine import pad_cache_to
+    cfg, params, w = smoke
+    eng = ServingEngine(cfg, params, max_len=64)
+    prompt = seeds.rng(6, 1).integers(0, cfg.vocab, 21).tolist()
+    last, cache = eng._prefill_tok(jnp.asarray([prompt], jnp.int32),
+                                   jnp.asarray([21], jnp.int32))
+    cache = pad_cache_to(cache, 21, 64, cfg)
+    seq = list(prompt)
+    tok = int(jnp.argmax(last[0]))
+    for _ in range(5):
+        seq.append(tok)
+        logits, cache = eng._decode(cache, jnp.asarray([[tok]], jnp.int32),
+                                    jnp.asarray([len(seq) - 1], jnp.int32))
+        tok = int(jnp.argmax(logits[0]))
+    (layer,) = cache["groups"][0]["layers"]
+    ref = granite.kv_at(w, seq, theta=cfg.rope_theta, eps=cfg.norm_eps)
+    for name, r in zip(("k", "v"), ref):
+        got = np.asarray(layer["attn"][name])
+        got = got[:, 0] if got.ndim == 5 else got[None, 0]
+        assert r.shape == (cfg.n_layers, len(seq), cfg.n_kv_heads,
+                           cfg.head_dim)
+        np.testing.assert_allclose(got[:, :len(seq)], r, rtol=0, atol=2e-4)
+        assert not np.any(got[:, len(seq):])
+
+
 def test_granite_control_departs_from_the_reference(smoke):
     cfg, params, w = smoke
     seq = seeds.rng(3, 1).integers(0, cfg.vocab, 40).tolist()

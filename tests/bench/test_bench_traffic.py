@@ -33,15 +33,44 @@ def test_same_seed_same_requests(name):
 def test_every_seed_same_sizes_in_each_block(name):
     mix = SERVE[name]
     block = mix["block"]
-    counts = None
+    base = (Counter(traffic.block_lengths(mix["prompt"], block)),
+            Counter(traffic.block_lengths(mix["output"], block)))
+    first = None
     for seed in SEEDS:
-        p = traffic.draw(mix["prompt"], block, 3 * block, seeds.rng(seed, 1))
-        o = traffic.draw(mix["output"], block, 3 * block, seeds.rng(seed, 1))
+        prompts, outs = traffic.requests(mix, 1000, 3 * block,
+                                         seeds.rng(seed, 1))
+        lens = ([len(p) for p in prompts], outs)
         for i in range(3):
-            got = (Counter(p[i * block:(i + 1) * block].tolist()),
-                   Counter(o[i * block:(i + 1) * block].tolist()))
-            counts = counts or got
-            assert got == counts
+            got = (Counter(lens[0][i * block:(i + 1) * block]),
+                   Counter(lens[1][i * block:(i + 1) * block]))
+            assert got == base
+        # the same sizes at the same places: the seed sets token ids only
+        first = first or lens
+        assert lens == first
+
+
+@pytest.mark.parametrize("name", sorted(SERVE))
+def test_any_run_of_requests_samples_the_whole_distribution(name):
+    """A window that admits a dozen consecutive requests, from anywhere in
+    the queue, meets sizes whose mean is near the block's."""
+    mix = SERVE[name]
+    block = mix["block"]
+    for spec, step in ((mix["prompt"], traffic.PROMPT_STEP),
+                       (mix["output"], traffic.OUTPUT_STEP)):
+        lens = traffic.draw(spec, block, 4 * block, step)
+        mean = np.mean(traffic.block_lengths(spec, block))
+
+        def worst(seq):
+            return max(abs(np.mean(seq[i:i + 12]) / mean - 1)
+                       for i in range(3 * block))
+
+        assert worst(lens) <= 0.21, (name, spec)
+        # every seeded shuffle of the same blocks strays further
+        for seed in range(10):
+            rng = np.random.default_rng(seed)
+            shuffled = np.concatenate([rng.permutation(lens[:block])
+                                       for _ in range(4)])
+            assert worst(shuffled) > worst(lens)
 
 
 @pytest.mark.parametrize("name", sorted(SERVE))
@@ -53,8 +82,8 @@ def test_prefill_shapes_bounded(name):
     assert all(spec["min"] <= s <= spec["max"] for s in shapes)
     assert all(s % spec.get("round_to", 1) == 0 for s in shapes)
     for seed in SEEDS:
-        drawn = traffic.draw(spec, mix["block"], 500, seeds.rng(seed, 1))
-        assert set(drawn.tolist()) <= set(shapes)
+        prompts, _ = traffic.requests(mix, 1000, 500, seeds.rng(seed, 1))
+        assert {len(p) for p in prompts} <= set(shapes)
     # every request fits the cache it is served from
     assert max(shapes) + mix["output"]["max"] <= mix["max_len"]
 
