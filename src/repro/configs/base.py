@@ -60,6 +60,8 @@ class ModelConfig:
     sliding_window: int = 0        # >0 enables SWA layers
     global_every: int = 0          # 1 global layer per this many (gemma 5:1 -> 6)
     rope_theta: float = 10_000.0
+    rope: bool = True              # False: no position embedding (NoPE)
+    attention_multiplier: Optional[float] = None  # score scale; None = 1/sqrt(head_dim)
     # --- moe ---
     n_experts: int = 0
     experts_per_tok: int = 0
@@ -71,6 +73,10 @@ class ModelConfig:
     moe_dispatch: str = "cumsum"   # cumsum (GShard-style positions) | sort
                                    # (megablocks-style; O(T log T), avoids the
                                    # global cumsum that blows up under SPMD)
+    experts_held: Optional[Tuple[int, int]] = None  # [lo, hi): the routed
+                                   # experts this layer holds (expert
+                                   # parallelism's share); the router keeps
+                                   # all n_experts, no token is dropped
     # --- ssm (mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -78,6 +84,7 @@ class ModelConfig:
     d_inner: int = 0
     ssm_chunk: int = 256
     conv_width: int = 4
+    mamba_conv_bias: bool = False  # bias on the depthwise convs of x, B, C
     # --- enc-dec ---
     is_encoder_decoder: bool = False
     n_encoder_layers: int = 0
@@ -85,6 +92,10 @@ class ModelConfig:
     # --- io ---
     embed_inputs: bool = False     # vlm/audio stub: inputs are embeddings
     tie_embeddings: bool = False
+    # Granite-style scalars (1.0 adds no operation)
+    embedding_multiplier: float = 1.0   # embeddings times this
+    residual_multiplier: float = 1.0    # each mixer's and FFN's output times this
+    logits_scaling: float = 1.0         # logits divided by this
     # --- numerics / distribution ---
     param_dtype: str = "bfloat16"
     opt_state_dtype: str = "float32"
@@ -164,6 +175,14 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    @property
+    def n_experts_here(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        if self.experts_held is None:
+            return self.n_experts
+        lo, hi = self.experts_held
+        return hi - lo
+
 
 # -------------------------------------------------------------- shape config
 
@@ -215,6 +234,7 @@ ARCH_IDS = [
     "jamba_1_5_large",
     "moonshot_v1_16b_a3b",
     "deepseek_moe_16b",
+    "granite_4_0_h_small",
     "paper_fpdiv",
 ]
 

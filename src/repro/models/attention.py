@@ -1,8 +1,9 @@
 """Attention: GQA full/causal, sliding-window (block-local), cross, and decode.
 
 Softmax denominators route through the paper's division unit
-(core.division_modes.softmax). The 1/sqrt(head_dim) score scale is a
-compile-time constant (no runtime division).
+(core.division_modes.softmax). The score scale (1/sqrt(head_dim), or the
+config's ``attention_multiplier``) is a compile-time constant (no runtime
+division); ``rope=False`` configs (NoPE) apply no position embedding.
 
 Memory strategy: full attention is query-chunked (scan over query blocks,
 keys whole) so 32k-token prefill never materializes an S x S score tensor;
@@ -62,7 +63,7 @@ def full_attention(p, x, positions, cfg: ModelConfig, *, causal: bool = True,
     """Training/prefill full attention, query-chunked above cfg.attn_chunk."""
     b, s, d = x.shape
     div = cfg.division
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = score_scale(cfg)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if kv_override is not None:  # cross attention: k/v precomputed, no rope
         k, v = kv_override
@@ -108,7 +109,7 @@ def sliding_attention(p, x, positions, cfg: ModelConfig):
     b, s, d = x.shape
     w = cfg.sliding_window
     div = cfg.division
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = score_scale(cfg)
     q, k, v = _proj_qkv(p, x, cfg)
     q = rope_apply(q, positions, cfg)
     k = rope_apply(k, positions, cfg)
@@ -151,7 +152,19 @@ def sliding_attention(p, x, positions, cfg: ModelConfig):
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"])
 
 
+def score_scale(cfg: ModelConfig) -> float:
+    """The attention score scale: ``attention_multiplier`` where the config
+    sets one, else 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return 1.0 / math.sqrt(cfg.head_dim)
+
+
 def rope_apply(x, positions, cfg: ModelConfig):
+    """RoPE at ``positions``; ``x`` unchanged where the config has no
+    position embedding (``rope=False``, NoPE)."""
+    if not cfg.rope:
+        return x
     from .layers import rope
 
     return rope(x, positions, cfg.rope_theta)
@@ -204,7 +217,7 @@ def decode_attention(p, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
     """
     b, one, d = x.shape
     div = cfg.division
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = score_scale(cfg)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if kv_override is not None:
         k_all = _repeat_kv(kv_override[0], cfg.q_per_kv)

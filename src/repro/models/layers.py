@@ -36,10 +36,26 @@ def gated_mlp(p, x):
 
 
 def embed_tokens(embed, tokens, cfg: ModelConfig):
-    return jnp.take(embed, tokens, axis=0)
+    x = jnp.take(embed, tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def residual_scale(y, cfg: ModelConfig):
+    """A mixer's or FFN's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return y
 
 
 def lm_logits(params, x, cfg: ModelConfig):
+    """f32 logits; divided by ``logits_scaling`` through the division unit
+    (one divisor for the whole plane) where the config sets one."""
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.einsum("...d,dv->...v", x, head,
-                      preferred_element_type=jnp.float32)
+    logits = jnp.einsum("...d,dv->...v", x, head,
+                        preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = dm.div(logits, jnp.full((1, 1), cfg.logits_scaling,
+                                         jnp.float32), cfg.division)
+    return logits

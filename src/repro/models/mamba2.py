@@ -21,13 +21,28 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import division_modes as dm
 
+# Named scopes (op metadata only): every Mamba layer runs under
+# ``mamba.mixer`` (models/model.py), the chunked SSD of prefill under
+# ``mamba.scan`` and the decode recurrence with its conv-state shift under
+# ``mamba.step``.
+SCOPE_MIXER = "mamba.mixer"
+SCOPE_SCAN = "mamba.scan"
+SCOPE_STEP = "mamba.step"
 
-def _causal_conv(u, w, width: int):
+
+def _conv_bias(p, name: str):
+    """The conv's bias (``mamba_conv_bias``), or None."""
+    return p.get(f"conv_{name}_bias")
+
+
+def _causal_conv(u, w, width: int, bias=None):
     """Depthwise causal conv via explicit shifts. u: (b, l, c); w: (width, c)."""
     out = u * w[-1]
     for k in range(1, width):
         shifted = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))[:, : u.shape[1]]
         out = out + shifted * w[-1 - k]
+    if bias is not None:
+        out = out + bias
     return out
 
 
@@ -40,29 +55,22 @@ def _segsum_decay(a):
     return jnp.where(mask, jnp.exp(diff), 0.0)
 
 
-def mamba_mixer(p: Dict, x, cfg: ModelConfig, *, initial_state=None,
-                return_state: bool = False, lengths=None):
-    """x: (b, l, d_model) -> (b, l, d_model). Chunked SSD over cfg.ssm_chunk.
-
-    ``lengths`` (per-request real lengths of a right-padded batch) zeroes dt
-    at pad positions, so decay = exp(0*A) = 1 and input contribution dt*B*x = 0
-    there: the returned state equals the state after each row's real tokens.
-    """
-    b, l, _ = x.shape
+def _ssd(p: Dict, xs_raw, B_raw, C_raw, dt_raw, cfg: ModelConfig, *,
+         initial_state=None, lengths=None):
+    """The chunked SSD of a whole sequence: convs, gates and the state
+    scan. Returns (y (b, l, d_inner) f32, last state (b, h, p, n))."""
+    b, l = xs_raw.shape[:2]
     h, pdim, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     q = min(cfg.ssm_chunk, l)
     assert l % q == 0, f"seq {l} not divisible by chunk {q}"
     nc = l // q
 
-    z = jnp.einsum("bld,di->bli", x, p["wz"])
-    xs_raw = jnp.einsum("bld,di->bli", x, p["wx"])
-    B_raw = jnp.einsum("bld,dn->bln", x, p["wB"])
-    C_raw = jnp.einsum("bld,dn->bln", x, p["wC"])
-    dt_raw = jnp.einsum("bld,dh->blh", x, p["wdt"]).astype(jnp.float32)
-
-    xs = _causal_conv(xs_raw, p["conv_x"], cfg.conv_width)
-    Bc = _causal_conv(B_raw, p["conv_B"], cfg.conv_width).astype(jnp.float32)
-    Cc = _causal_conv(C_raw, p["conv_C"], cfg.conv_width).astype(jnp.float32)
+    xs = _causal_conv(xs_raw, p["conv_x"], cfg.conv_width,
+                      _conv_bias(p, "x"))
+    Bc = _causal_conv(B_raw, p["conv_B"], cfg.conv_width,
+                      _conv_bias(p, "B")).astype(jnp.float32)
+    Cc = _causal_conv(C_raw, p["conv_C"], cfg.conv_width,
+                      _conv_bias(p, "C")).astype(jnp.float32)
     xs = jax.nn.silu(xs.astype(jnp.float32))
     Bc = jax.nn.silu(Bc)
     Cc = jax.nn.silu(Cc)
@@ -112,7 +120,27 @@ def mamba_mixer(p: Dict, x, cfg: ModelConfig, *, initial_state=None,
                          Cq, jnp.exp(acum), S_prevs)
     y = (y_intra + y_inter).reshape(b, l, h, pdim)
     y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh
-    y = y.reshape(b, l, h * pdim)
+    return y.reshape(b, l, h * pdim), S_last
+
+
+def mamba_mixer(p: Dict, x, cfg: ModelConfig, *, initial_state=None,
+                return_state: bool = False, lengths=None):
+    """x: (b, l, d_model) -> (b, l, d_model). Chunked SSD over cfg.ssm_chunk.
+
+    ``lengths`` (per-request real lengths of a right-padded batch) zeroes dt
+    at pad positions, so decay = exp(0*A) = 1 and input contribution dt*B*x = 0
+    there: the returned state equals the state after each row's real tokens.
+    """
+    b, l, _ = x.shape
+    z = jnp.einsum("bld,di->bli", x, p["wz"])
+    xs_raw = jnp.einsum("bld,di->bli", x, p["wx"])
+    B_raw = jnp.einsum("bld,dn->bln", x, p["wB"])
+    C_raw = jnp.einsum("bld,dn->bln", x, p["wC"])
+    dt_raw = jnp.einsum("bld,dh->blh", x, p["wdt"]).astype(jnp.float32)
+
+    with jax.named_scope(SCOPE_SCAN):
+        y, S_last = _ssd(p, xs_raw, B_raw, C_raw, dt_raw, cfg,
+                         initial_state=initial_state, lengths=lengths)
 
     # gated RMSNorm then output projection
     from .layers import rms_norm
@@ -161,10 +189,12 @@ def abstract_cache_mamba(cfg: ModelConfig, batch: int, dtype=jnp.float32):
         init_cache_mamba(cfg, batch, dtype))
 
 
-def _conv_step(u_new, conv_state, w):
+def _conv_step(u_new, conv_state, w, bias=None):
     """One-token causal conv. u_new: (b,1,c); conv_state: (b, width-1, c)."""
     window = jnp.concatenate([conv_state, u_new], axis=1)  # (b, width, c)
     out = jnp.einsum("bwc,wc->bc", window, w)[:, None, :]
+    if bias is not None:
+        out = out + bias
     return out, window[:, 1:]
 
 
@@ -179,23 +209,27 @@ def decode_mamba(p: Dict, x, cache, cfg: ModelConfig):
     Cc = jnp.einsum("bld,dn->bln", x, p["wC"])
     dt_raw = jnp.einsum("bld,dh->blh", x, p["wdt"]).astype(jnp.float32)
 
-    xs, cx = _conv_step(xs, cache["conv_x"], p["conv_x"])
-    Bc, cB = _conv_step(Bc, cache["conv_B"], p["conv_B"])
-    Cc, cC = _conv_step(Cc, cache["conv_C"], p["conv_C"])
-    xs = jax.nn.silu(xs.astype(jnp.float32))
-    Bc = jax.nn.silu(Bc.astype(jnp.float32))[:, 0]          # (b,n)
-    Cc = jax.nn.silu(Cc.astype(jnp.float32))[:, 0]
-    dt = jax.nn.softplus(dt_raw + p["dt_bias"].astype(jnp.float32))[:, 0]  # (b,h)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope(SCOPE_STEP):
+        xs, cx = _conv_step(xs, cache["conv_x"], p["conv_x"],
+                            _conv_bias(p, "x"))
+        Bc, cB = _conv_step(Bc, cache["conv_B"], p["conv_B"],
+                            _conv_bias(p, "B"))
+        Cc, cC = _conv_step(Cc, cache["conv_C"], p["conv_C"],
+                            _conv_bias(p, "C"))
+        xs = jax.nn.silu(xs.astype(jnp.float32))
+        Bc = jax.nn.silu(Bc.astype(jnp.float32))[:, 0]          # (b,n)
+        Cc = jax.nn.silu(Cc.astype(jnp.float32))[:, 0]
+        dt = jax.nn.softplus(dt_raw + p["dt_bias"].astype(jnp.float32))[:, 0]  # (b,h)
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    xh = xs.reshape(b, h, pdim)
-    S = cache["state"]
-    decay = jnp.exp(dt * A)                                  # (b,h)
-    S_new = (decay[..., None, None] * S
-             + jnp.einsum("bh,bn,bhp->bhpn", dt, Bc, xh))
-    y = jnp.einsum("bn,bhpn->bhp", Cc, S_new)
-    y = y + p["D"].astype(jnp.float32)[None, :, None] * xh
-    y = y.reshape(b, 1, h * pdim)
+        xh = xs.reshape(b, h, pdim)
+        S = cache["state"]
+        decay = jnp.exp(dt * A)                                  # (b,h)
+        S_new = (decay[..., None, None] * S
+                 + jnp.einsum("bh,bn,bhp->bhpn", dt, Bc, xh))
+        y = jnp.einsum("bn,bhpn->bhp", Cc, S_new)
+        y = y + p["D"].astype(jnp.float32)[None, :, None] * xh
+        y = y.reshape(b, 1, h * pdim)
 
     from .layers import rms_norm
     y = y * jax.nn.silu(z.astype(jnp.float32))

@@ -19,9 +19,9 @@ import jax.numpy as jnp
 from repro.configs.base import Group, LayerSpec, ModelConfig
 from .attention import (abstract_cache_attn, decode_attention, full_attention,
                         init_cache_attn, sliding_attention)
-from .layers import embed_tokens, gated_mlp, lm_logits, rms_norm
-from .mamba2 import (abstract_cache_mamba, decode_mamba, init_cache_mamba,
-                     mamba_mixer)
+from .layers import embed_tokens, gated_mlp, lm_logits, residual_scale, rms_norm
+from .mamba2 import (SCOPE_MIXER, abstract_cache_mamba, decode_mamba,
+                     init_cache_mamba, mamba_mixer)
 from .moe import moe_ffn
 
 
@@ -83,15 +83,17 @@ def block_forward(bp: Dict, x, spec: LayerSpec, cfg: ModelConfig, positions,
 
     h = rms_norm(x, bp["mixer_norm"], div, cfg.norm_eps)
     if spec.mixer == "mamba":
-        if mode == "decode":
-            mh, new_cache["mamba"] = decode_mamba(bp["mamba"], h, cache["mamba"], cfg)
-        elif mode == "prefill":
-            mh, new_cache["mamba"] = mamba_mixer(bp["mamba"], h, cfg,
-                                                 return_state=True,
-                                                 lengths=lengths)
-        else:
-            mh = mamba_mixer(bp["mamba"], h, cfg)
-        x = x + mh
+        with jax.named_scope(SCOPE_MIXER):
+            if mode == "decode":
+                mh, new_cache["mamba"] = decode_mamba(bp["mamba"], h,
+                                                      cache["mamba"], cfg)
+            elif mode == "prefill":
+                mh, new_cache["mamba"] = mamba_mixer(bp["mamba"], h, cfg,
+                                                     return_state=True,
+                                                     lengths=lengths)
+            else:
+                mh = mamba_mixer(bp["mamba"], h, cfg)
+        x = x + residual_scale(mh, cfg)
     else:
         window = cfg.sliding_window if spec.mixer == "swa" else 0
         if mode == "decode":
@@ -107,7 +109,7 @@ def block_forward(bp: Dict, x, spec: LayerSpec, cfg: ModelConfig, positions,
                     v = _ring_from_prefill(v, window, lengths)
                 new_cache["attn"] = {"k": k.astype(cfg.param_dtype),
                                      "v": v.astype(cfg.param_dtype)}
-        x = x + ah
+        x = x + residual_scale(ah, cfg)
 
     if "cross" in bp:  # encoder-decoder cross attention
         hc = rms_norm(x, bp["cross_norm"], div, cfg.norm_eps)
@@ -132,7 +134,7 @@ def block_forward(bp: Dict, x, spec: LayerSpec, cfg: ModelConfig, positions,
             aux = aux + a
         else:
             ff = gated_mlp(bp["ffn"], h2)
-        x = x + ff
+        x = x + residual_scale(ff, cfg)
     return x, new_cache, aux
 
 

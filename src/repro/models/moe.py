@@ -9,7 +9,19 @@ over capacity drop to the residual path. Expert weights carry the 'experts'
 logical axis, so the same code runs EP (experts over a mesh axis, all-to-all
 inserted by GSPMD at the scatter/gather) or expert-TP ('expert_mlp' sharded).
 
+A layer that holds only some routed experts (``cfg.experts_held``, the
+share of one chip under expert parallelism) is a mode of the ``sort``
+dispatch: it routes over all E experts, sorts the (token, choice) pairs by
+expert, and runs its own experts' MLPs as grouped matmuls
+(``jax.lax.ragged_dot``) over the pairs routed to them, with no capacity
+and no drop. Pairs routed to experts held elsewhere contribute nothing
+here; the shared expert is added on every holder.
+
 Load-balance aux loss (Switch eq. 4): aux = E * sum_e f_e * P_e.
+
+Named scopes (op metadata only): ``moe.route`` (router, gates),
+``moe.experts`` (dispatch, the routed experts' matmuls, combine) and
+``moe.shared`` (the shared expert).
 """
 from __future__ import annotations
 
@@ -21,6 +33,10 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import division_modes as dm
+
+SCOPE_ROUTE = "moe.route"
+SCOPE_EXPERTS = "moe.experts"
+SCOPE_SHARED = "moe.shared"
 
 
 def _local_shard_count(T: int) -> int:
@@ -92,6 +108,38 @@ def _dispatch_local(p, xt, probs, gates, idx, cfg: ModelConfig, D: int):
     return out, counts_f
 
 
+def _held_experts(p, xt, flat_e, flat_g, cfg: ModelConfig):
+    """The held experts' part of the layer, no token dropped: (T, d).
+
+    ``flat_e``/``flat_g``: expert id and gate of each (token, choice) pair,
+    token-major. Pairs are sorted by held expert, the pairs of experts held
+    elsewhere last, and each held expert's gated MLP runs as a grouped
+    matmul over its run of pairs."""
+    lo, hi = cfg.experts_held
+    T, d = xt.shape
+    k = cfg.experts_per_tok
+    Eh = hi - lo
+    held = (flat_e >= lo) & (flat_e < hi)
+    local = jnp.where(held, flat_e - lo, Eh)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.zeros((Eh + 1,), jnp.int32).at[local].add(1)[:Eh]
+    # Rows past the held experts' runs are in no group: what a grouped
+    # matmul leaves there is not defined (zeros on the CPU, not on the TPU,
+    # where a zero gate times a non-finite leftover is nan), so each
+    # product is masked to the rows in a group.
+    mine = held[order, None]
+    rows = jnp.where(mine, xt[order // k], 0).astype(xt.dtype)
+    h = jnp.where(mine, jax.lax.ragged_dot(rows, p["wi"], sizes), 0)
+    g = jax.nn.silu(jnp.where(mine, jax.lax.ragged_dot(rows, p["wg"], sizes),
+                              0).astype(jnp.float32))
+    eo = jax.lax.ragged_dot(g.astype(h.dtype) * h, p["wo"], sizes)
+    w = jnp.where(held, flat_g, 0.0)[order]
+    eo = jnp.where(mine, eo * w.astype(eo.dtype)[:, None], 0)
+    inv = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    return eo[inv].reshape(T, k, d).sum(axis=1)
+
+
 def moe_ffn(p: Dict, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     """x: (b, s, d) -> (out, aux_loss)."""
     b, s, d = x.shape
@@ -99,74 +147,90 @@ def moe_ffn(p: Dict, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     T = b * s
     xt = x.reshape(T, d)
 
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = dm.softmax(logits, axis=-1, cfg=cfg.division)          # (T, E)
-    gate_vals, idx = jax.lax.top_k(probs, k)                       # (T, k)
-    # top-k renormalization — another divider site
-    denom = jnp.sum(gate_vals, axis=-1, keepdims=True)
-    gates = gate_vals * dm.recip(denom, cfg.division)              # (T, k)
+    with jax.named_scope(SCOPE_ROUTE):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        probs = dm.softmax(logits, axis=-1, cfg=cfg.division)      # (T, E)
+        gate_vals, idx = jax.lax.top_k(probs, k)                   # (T, k)
+        # top-k renormalization — another divider site
+        denom = jnp.sum(gate_vals, axis=-1, keepdims=True)
+        gates = gate_vals * dm.recip(denom, cfg.division)          # (T, k)
+
+    def shared(out):
+        if not cfg.n_shared_experts:
+            return out
+        from .layers import gated_mlp
+        with jax.named_scope(SCOPE_SHARED):
+            return out + gated_mlp(p["shared"], xt)
+
+    def aux_loss(counts):
+        f_e = counts / (T * k) * E
+        P_e = jnp.mean(probs, axis=0)
+        return E * jnp.sum(f_e * P_e) * cfg.router_aux_weight
+
+    if cfg.experts_held is not None:
+        if cfg.moe_dispatch != "sort":
+            raise ValueError(
+                f"{cfg.name}: experts_held is a mode of the 'sort' dispatch, "
+                f"not of {cfg.moe_dispatch!r}")
+        flat_e = idx.reshape(T * k)
+        with jax.named_scope(SCOPE_EXPERTS):
+            out = _held_experts(p, xt, flat_e, gates.reshape(T * k), cfg)
+        out = shared(out)
+        counts = jnp.zeros((E,), jnp.float32).at[flat_e].add(1.0)
+        return out.reshape(b, s, d), aux_loss(counts)
 
     # Capacity floor: tiny token counts (decode steps) get no-drop capacity so
     # serving is deterministic; large batches use the standard cf bound.
     if cfg.moe_dispatch == "local":
         D = _local_shard_count(T)
-        out, counts = _dispatch_local(p, xt, probs, gates, idx, cfg, D)
-        if cfg.n_shared_experts:
-            from .layers import gated_mlp
-            out = out + gated_mlp(p["shared"], xt)
-        f_e = counts / (T * k) * E
-        P_e = jnp.mean(probs, axis=0)
-        aux = E * jnp.sum(f_e * P_e) * cfg.router_aux_weight
-        return out.reshape(b, s, d), aux
+        with jax.named_scope(SCOPE_EXPERTS):
+            out, counts = _dispatch_local(p, xt, probs, gates, idx, cfg, D)
+        return shared(out).reshape(b, s, d), aux_loss(counts)
 
     capacity = max(math.ceil(T * k / E * cfg.capacity_factor), min(T * k, 8))
 
     flat_e = idx.reshape(T * k)                                    # expert ids
     flat_g = gates.reshape(T * k)
-    if cfg.moe_dispatch == "sort":
-        # megablocks-style: stable-sort by expert, position = rank within the
-        # expert's run. O(Tk log Tk); same first-come-first-served drops as
-        # the cumsum scheme, but no O(Tk*E) global cumsum (which XLA models
-        # as reduce-window and SPMD executes near-quadratically).
-        order = jnp.argsort(flat_e, stable=True)
-        sorted_e = flat_e[order]
-        first = jnp.searchsorted(sorted_e, jnp.arange(E))          # (E,)
-        pos_sorted = jnp.arange(T * k) - first[sorted_e]
-        flat_pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            pos_sorted.astype(jnp.int32))
-    else:
-        onehot_pos = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)    # (T*k, E)
-        pos_in_e = jnp.cumsum(onehot_pos, axis=0) * onehot_pos     # 1-based
-        flat_pos = jnp.sum(pos_in_e, axis=-1) - 1                  # (T*k,)
-    keep = (flat_pos >= 0) & (flat_pos < capacity)
-    flat_pos = jnp.clip(flat_pos, 0, capacity - 1)
+    with jax.named_scope(SCOPE_EXPERTS):
+        if cfg.moe_dispatch == "sort":
+            # megablocks-style: stable-sort by expert, position = rank within
+            # the expert's run. O(Tk log Tk); same first-come-first-served
+            # drops as the cumsum scheme, but no O(Tk*E) global cumsum (which
+            # XLA models as reduce-window and SPMD executes
+            # near-quadratically).
+            order = jnp.argsort(flat_e, stable=True)
+            sorted_e = flat_e[order]
+            first = jnp.searchsorted(sorted_e, jnp.arange(E))      # (E,)
+            pos_sorted = jnp.arange(T * k) - first[sorted_e]
+            flat_pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
+                pos_sorted.astype(jnp.int32))
+        else:
+            onehot_pos = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*k, E)
+            pos_in_e = jnp.cumsum(onehot_pos, axis=0) * onehot_pos   # 1-based
+            flat_pos = jnp.sum(pos_in_e, axis=-1) - 1                # (T*k,)
+        keep = (flat_pos >= 0) & (flat_pos < capacity)
+        flat_pos = jnp.clip(flat_pos, 0, capacity - 1)
 
-    # dispatch: (E, C, d)
-    xr = jnp.repeat(xt, k, axis=0)                                 # (T*k, d)
-    contrib = jnp.where(keep[:, None], xr, 0).astype(x.dtype)
-    buf = jnp.zeros((E, capacity, d), x.dtype)
-    buf = buf.at[flat_e, flat_pos].add(contrib)
+        # dispatch: (E, C, d)
+        xr = jnp.repeat(xt, k, axis=0)                             # (T*k, d)
+        contrib = jnp.where(keep[:, None], xr, 0).astype(x.dtype)
+        buf = jnp.zeros((E, capacity, d), x.dtype)
+        buf = buf.at[flat_e, flat_pos].add(contrib)
 
-    # expert compute: gated MLP batched over experts
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["wg"]).astype(jnp.float32))
-    eo = jnp.einsum("ecf,efd->ecd", g.astype(h.dtype) * h, p["wo"])
+        # expert compute: gated MLP batched over experts
+        h = jnp.einsum("ecd,edf->ecf", buf, p["wi"])
+        g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["wg"])
+                        .astype(jnp.float32))
+        eo = jnp.einsum("ecf,efd->ecd", g.astype(h.dtype) * h, p["wo"])
 
-    # combine
-    tok_out = eo[flat_e, flat_pos]                                 # (T*k, d)
-    tok_out = tok_out * (flat_g * keep).astype(tok_out.dtype)[:, None]
-    out = tok_out.reshape(T, k, d).sum(axis=1)
-
-    if cfg.n_shared_experts:
-        from .layers import gated_mlp
-        out = out + gated_mlp(p["shared"], xt)
+        # combine
+        tok_out = eo[flat_e, flat_pos]                             # (T*k, d)
+        tok_out = tok_out * (flat_g * keep).astype(tok_out.dtype)[:, None]
+        out = tok_out.reshape(T, k, d).sum(axis=1)
+    out = shared(out)
 
     # load-balance aux (scatter-add counts; no (T*k, E) one-hot materialized)
     counts = jnp.zeros((E,), jnp.float32).at[flat_e].add(
         keep.astype(jnp.float32))
-    f_e = counts / (T * k) * E
-    P_e = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(f_e * P_e) * cfg.router_aux_weight
-
-    return out.reshape(b, s, d), aux
+    return out.reshape(b, s, d), aux_loss(counts)

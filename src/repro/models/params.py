@@ -61,10 +61,12 @@ def _mlp_specs(cfg: ModelConfig, d_ff: int) -> Dict[str, ParamSpec]:
 
 
 def _moe_specs(cfg: ModelConfig) -> Dict[str, Any]:
-    d, E, f = cfg.d_model, cfg.n_experts, cfg.d_ff_expert
+    # The router spans every routed expert; the expert weights only those
+    # the layer holds (``experts_held``).
+    d, E, f = cfg.d_model, cfg.n_experts_here, cfg.d_ff_expert
     out: Dict[str, Any] = {
-        "router": ParamSpec((d, E), ("embed", None), scale=1.0 / np.sqrt(d),
-                            dtype="float32"),
+        "router": ParamSpec((d, cfg.n_experts), ("embed", None),
+                            scale=1.0 / np.sqrt(d), dtype="float32"),
         "wi": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"),
                         scale=1.0 / np.sqrt(d)),
         "wg": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"),
@@ -81,7 +83,7 @@ def _mamba_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, din, n, h = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     w = cfg.conv_width
     s = 1.0 / np.sqrt(d)
-    return {
+    out = {
         "wz": ParamSpec((d, din), ("embed", "ssm_inner"), scale=s),
         "wx": ParamSpec((d, din), ("embed", "ssm_inner"), scale=s),
         "wB": ParamSpec((d, n), ("embed", "ssm_state"), scale=s),
@@ -96,6 +98,12 @@ def _mamba_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
         "norm": ParamSpec((din,), ("ssm_inner",), init="ones", dtype="float32"),
         "wout": ParamSpec((din, d), ("ssm_inner", "embed"), scale=1.0 / np.sqrt(din)),
     }
+    if cfg.mamba_conv_bias:
+        sb = 1.0 / np.sqrt(w)
+        out["conv_x_bias"] = ParamSpec((din,), ("ssm_inner",), scale=sb)
+        out["conv_B_bias"] = ParamSpec((n,), ("ssm_state",), scale=sb)
+        out["conv_C_bias"] = ParamSpec((n,), ("ssm_state",), scale=sb)
+    return out
 
 
 def _block_specs(cfg: ModelConfig, spec: LayerSpec, cross: bool = False) -> Dict[str, Any]:

@@ -15,7 +15,8 @@ position ``len(prompt_i) + t``, progressively overwriting the pad slots.
 ``generate``.
 
 ``ServingEngine.serve`` is the continuous-batching loop: admit a request
-into a free batch slot (single-row prefill + cache row insert), decode all
+into a free batch slot (single-row prefill, then one jitted program that
+writes the row into the donated batch cache), decode all
 active slots in lockstep (one jit'd step), release on EOS / ``max_new``,
 refill from the queue. ``generate``/``generate_batch`` are the static-batch
 special case. The division unit is a serving knob: pass ``division=`` to run
@@ -109,8 +110,9 @@ def pad_cache_to(cache, from_len: int, to_len: int, cfg: ModelConfig = None):
     return {"groups": new_groups}
 
 
-def _insert_cache_row(cache, row, slot: int, cfg: ModelConfig):
-    """Write single-request cache ``row`` (batch 1) into batch slot ``slot``.
+def _insert_cache_row(cache, row, slot, cfg: ModelConfig):
+    """Write single-request cache ``row`` (batch 1) into batch slot ``slot``
+    (an int or a traced int32 scalar).
 
     Leaves of groups with ``repeat > 1`` carry a leading stacked-layers dim,
     so the batch axis is 1 there and 0 elsewhere."""
@@ -156,21 +158,45 @@ class ServingEngine:
         # The weights are an argument of every program, never a closure:
         # a closed-over array is lowered as an HLO constant, which would
         # bake the whole checkpoint into each prefill and decode program.
-        self._decode_fn = jax.jit(
-            lambda w, c, t, p: decode_step(cfg, w, c, t, p))
-        self._prefill_tok_fn = jax.jit(
-            lambda w, t, l: prefill(cfg, w, t, lengths=l))
-        self._prefill_emb_fn = jax.jit(
-            lambda w, e, l: prefill(cfg, w, None, embeds=e, lengths=l))
-        self._prefill_enc_fn = jax.jit(
-            lambda w, t, enc, l: prefill(cfg, w, t, enc_embeds=enc,
-                                         lengths=l))
+        # Each program is named (its HLO module is ``jit_<name>``), so that
+        # a device trace tells the decode step from a prefill.
+        def decode(w, c, t, p):
+            return decode_step(cfg, w, c, t, p)
+
+        def prefill_tokens(w, t, l):
+            return prefill(cfg, w, t, lengths=l)
+
+        def prefill_embeds(w, e, l):
+            return prefill(cfg, w, None, embeds=e, lengths=l)
+
+        def prefill_encoder(w, t, enc, l):
+            return prefill(cfg, w, t, enc_embeds=enc, lengths=l)
+
+        # An admission's row goes into the batch cache in one program that
+        # updates the donated cache in place: one dispatch, and no copy of
+        # any cache leaf.
+        def insert_row(c, r, slot, pad_to):
+            return _insert_cache_row(
+                c, pad_cache_to(r, pad_to, max_len, cfg), slot, cfg)
+
+        self._decode_fn = jax.jit(decode)
+        self._prefill_tok_fn = jax.jit(prefill_tokens)
+        self._prefill_emb_fn = jax.jit(prefill_embeds)
+        self._prefill_enc_fn = jax.jit(prefill_encoder)
+        self._insert_fn = jax.jit(insert_row, static_argnums=(3,),
+                                  donate_argnums=(0,))
 
     def _decode(self, cache, tokens, pos):
         return self._decode_fn(self.params, cache, tokens, pos)
 
     def _prefill_tok(self, tokens, lengths):
         return self._prefill_tok_fn(self.params, tokens, lengths)
+
+    def _insert(self, cache, row, slot: int, pad_to: int):
+        """``cache`` with the prefill cache ``row`` (batch 1, prompt padded
+        to ``pad_to``) written into batch slot ``slot``; ``cache`` is
+        donated."""
+        return self._insert_fn(cache, row, np.int32(slot), pad_to)
 
     # ------------------------------------------------------------- alignment
 
@@ -329,8 +355,7 @@ class ServingEngine:
             toks[0, :s] = req.tokens
             last, row = self._prefill_tok(jnp.asarray(toks),
                                           jnp.asarray([s], jnp.int32))
-            row = pad_cache_to(row, pad_to, self.max_len, cfg)
-            cache = _insert_cache_row(cache, row, slot, cfg)
+            cache = self._insert(cache, row, slot, pad_to)
             cur[slot, 0] = int(jnp.argmax(last[0]))
             pos_v[slot] = s
             active[slot] = req

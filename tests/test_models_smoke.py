@@ -32,6 +32,7 @@ EXPECTED_PARAMS_B = {
     "jamba_1_5_large": (397.6, 5.0),
     "moonshot_v1_16b_a3b": (28.4, 1.0),   # 48L pinned by the assignment
     "deepseek_moe_16b": (16.4, 0.6),
+    "granite_4_0_h_small": (32.2, 1.0),   # published 32B total
     "paper_fpdiv": (0.134, 0.02),
 }
 
@@ -39,6 +40,7 @@ EXPECTED_ACTIVE_B = {
     "jamba_1_5_large": (93.2, 2.0),
     "deepseek_moe_16b": (2.83, 0.2),
     "moonshot_v1_16b_a3b": (4.8, 0.3),
+    "granite_4_0_h_small": (8.8, 0.5),    # published 9B active
 }
 
 
