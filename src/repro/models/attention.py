@@ -22,6 +22,8 @@ from repro.core import division_modes as dm
 from repro.configs.base import ModelConfig
 
 NEG_INF = -1e30
+# decode attention (decode_attention), op metadata for device traces
+SCOPE_DECODE = "attn.decode"
 
 
 def _proj_qkv(p, x, cfg: ModelConfig):
@@ -202,6 +204,33 @@ def decode_positions(pos, batch: int):
     return pos_v
 
 
+def _decode_sdpa(q, k, v, valid, div: dm.DivisionConfig, scale: float):
+    """One query token per slot against a cache in its stored layout.
+
+    q: (b, 1, h, hd); k/v: (b, L, kv, hd); valid: (b, L) cache slots to
+    attend, or None for all of them.
+
+    Query head ``kv_i * g + j`` (``g = h // kv``, the order ``_repeat_kv``
+    gives) attends kv head ``kv_i``: the products run over (kv head, query
+    group), so the cache is read once and never repeated ``g``-fold. The
+    softmax operand keeps its ``b*h`` rows of ``L``.
+    """
+    from repro.sharding.rules import shard_dim
+
+    b, _, h, hd = q.shape
+    kv = k.shape[2]
+    qg = shard_dim(q.reshape(b, kv, h // kv, hd), 1)
+    scores = jnp.einsum("bkgd,btkd->bkgt", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = shard_dim(scores, 1)
+    if valid is not None:
+        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    probs = dm.softmax(scores, axis=-1, cfg=div)
+    out = jnp.einsum("bkgt,btkd->bkgd", probs.astype(v.dtype), v)
+    return shard_dim(out, 1).reshape(b, 1, h, hd)
+
+
+@jax.named_scope(SCOPE_DECODE)
 def decode_attention(p, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
                      kv_override: Optional[Tuple] = None):
     """One-token decode. x: (b, 1, d); cache k/v: (b, L, kv, hd); pos: scalar
@@ -220,10 +249,7 @@ def decode_attention(p, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
     scale = score_scale(cfg)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if kv_override is not None:
-        k_all = _repeat_kv(kv_override[0], cfg.q_per_kv)
-        v_all = _repeat_kv(kv_override[1], cfg.q_per_kv)
-        mask = jnp.ones((1, 1, 1, 1), bool)
-        out = _sdpa(q, k_all, v_all, mask, div, scale)
+        out = _decode_sdpa(q, *kv_override, None, div, scale)
         return jnp.einsum("bqhk,hkd->bqd", out, p["wo"]), cache
     pos_v = decode_positions(pos, b)
     posv = pos_v[:, None]
@@ -235,8 +261,6 @@ def decode_attention(p, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
     bidx = jnp.arange(b)
     k_c = cache["k"].at[bidx, slot_v].set(k_new[:, 0].astype(cache["k"].dtype))
     v_c = cache["v"].at[bidx, slot_v].set(v_new[:, 0].astype(cache["v"].dtype))
-    k_all = _repeat_kv(k_c, cfg.q_per_kv)
-    v_all = _repeat_kv(v_c, cfg.q_per_kv)
     idx = jnp.arange(L)
     if window == 0:
         valid = idx[None, :] <= pos_v[:, None]
@@ -246,6 +270,5 @@ def decode_attention(p, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
         # slot whose position would predate the sequence — never written.
         held = pos_v[:, None] - jnp.mod(pos_v[:, None] - idx[None, :], L)
         valid = held >= 0
-    mask = valid[:, None, None, :]
-    out = _sdpa(q, k_all, v_all, mask, div, scale)
+    out = _decode_sdpa(q, k_c, v_c, valid, div, scale)
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"]), {"k": k_c, "v": v_c}

@@ -273,3 +273,37 @@ def test_kmeans_scopes_on_cpu():
     assert {s for p in paths.values() for s in scopes.scopes_in(p)} >= {
         "kmeans.distance", "kmeans.assign", "kmeans.update", "kmeans.final",
         "dm.div"}
+
+
+def test_gqa_decode_attention_for_v5e(compiled_text):
+    """Decode attention at Granite-8B's head widths (32 query heads over 8
+    kv heads, head_dim 128) compiles for the chip without repeating the
+    cache per query head, and its softmax is the unit's kernel under the
+    decode scope."""
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.core import division_modes as dm
+    from repro.models.attention import SCOPE_DECODE, decode_attention
+
+    cfg = dataclasses.replace(
+        get_smoke_config("llama3_8b"), n_heads=32, n_kv_heads=8,
+        head_dim=128, d_model=4096,
+        division=dm.DivisionConfig(mode="taylor_pallas"))
+    b, L, bf16 = 8, 1024, jnp.bfloat16
+
+    def step(wq, wk, wv, wo, k, v, x, pos):
+        p = {"wq": wq, "wk": wk, "wv": wv, "wo": wo}
+        return decode_attention(p, x, {"k": k, "v": v}, pos, cfg)
+
+    text = compiled_text(
+        step, ((4096, 32, 128), bf16), ((4096, 8, 128), bf16),
+        ((4096, 8, 128), bf16), ((32, 128, 4096), bf16),
+        ((b, L, 8, 128), bf16), ((b, L, 8, 128), bf16),
+        ((b, 1, 4096), bf16), ((b,), jnp.int32))
+    assert f"[{b},{L},32,128]" not in text
+    softmax = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and f"{SCOPE_DECODE}/dm.softmax/" in ln]
+    # one row of L per slot and query head, as before the grouping
+    assert len(softmax) == 1 and f"f32[{b * 32},{L}]" in softmax[0]
